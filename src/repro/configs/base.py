@@ -86,6 +86,11 @@ class ModelConfig:
         reps = (self.n_layers + len(p) - 1) // len(p)
         return (p * reps)[: self.n_layers]
 
+    def production(self) -> "ModelConfig":
+        """The serving and dry-run dtypes: bf16 params and compute."""
+        return dataclasses.replace(self, param_dtype="bfloat16",
+                                   compute_dtype="bfloat16")
+
     def reduced(self, *, n_layers: int = 2, d_model: int = 64,
                 n_heads: int = 4, n_kv: int | None = None, d_ff: int | None = None,
                 vocab: int = 256, experts: int = 4) -> "ModelConfig":

@@ -26,7 +26,7 @@ bit-for-bit — the admission decision of the greedy solve hangs on float
 comparisons against the ``max_path_cost`` bar and the ``_BIG`` sentinel.
 Three properties guarantee it:
 
-1. all arithmetic runs in float64 (``jax.experimental.enable_x64`` around
+1. all arithmetic runs in float64 (``jax.enable_x64(True)`` around
    trace and dispatch — the rest of the repo stays on default f32), with the
    same per-element operation order as the numpy reference (gather-multiply,
    then + penalty, then + compute, then + carried cost);
@@ -46,8 +46,8 @@ power-of-two bucket (floor :data:`MIN_BUCKET`) before dispatch and sliced
 back after: re-solving with a different S only recompiles when S crosses a
 bucket boundary.  (M is pinned by the model profile and k by the ladder
 level, so those axes are naturally stable.)  Padded rows carry benign zeros
-and are never read back.  :func:`compile_count` exposes the jit cache size
-so tests can pin the contract.
+and are never read back.  :func:`compile_count` counts the shapes the sweep
+was traced for, so tests can pin the contract.
 """
 
 from __future__ import annotations
@@ -57,6 +57,7 @@ import numpy as np
 MIN_BUCKET = 8
 
 _kernel = None      # lazily built jitted sweep (keeps jax off the cold path)
+_n_traces = 0       # shapes the sweep was traced (and so compiled) for
 _spb_cache: tuple | None = None   # (numpy spb, device spb) — `is`-keyed
 
 
@@ -76,6 +77,8 @@ def _build_kernel():
     def sweep(spb, Kv, Ks, srcs, cand, pen, cc):
         """spb (N,N); srcs (S,); cand/pen (S,M,k); cc (M,N) or None
         → final (S,k) min-plus costs, backs (M-1,S,k) argmin back-pointers."""
+        global _n_traces
+        _n_traces += 1          # the body runs once per traced shape
         N = spb.shape[0]
         flat = spb.ravel()                         # flat take beats 2D gather
         c = Ks * jnp.take(flat, srcs[:, None] * N + cand[:, 0, :]) + pen[:, 0]
@@ -109,9 +112,7 @@ def _get_kernel():
 def compile_count() -> int:
     """Number of distinct shapes the sweep kernel has compiled for (tests pin
     the padding contract: same bucket ⇒ no recompilation)."""
-    if _kernel is None:
-        return 0
-    return int(_kernel._cache_size())
+    return _n_traces
 
 
 def _device_spb(spb: np.ndarray):
@@ -141,7 +142,7 @@ def solve_batch(spb: np.ndarray, Ks: float, compute_cost: np.ndarray | None,
     cost, bit-identical to running :func:`~repro.core.ould._sparse_run` on
     each row sequentially.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     Kv = np.asarray(consts[0], np.float64)
     S, M, kk = cand.shape
@@ -151,7 +152,7 @@ def solve_batch(spb: np.ndarray, Ks: float, compute_cost: np.ndarray | None,
         srcs = np.concatenate([srcs, np.zeros(Sp - S, srcs.dtype)])
         cand = np.concatenate([cand, np.zeros((Sp - S, M, kk), cand.dtype)])
         pen = np.concatenate([pen, np.zeros((Sp - S, M, kk))])
-    with enable_x64():
+    with jax.enable_x64(True):
         f, b = _get_kernel()(_device_spb(spb), Kv, np.float64(Ks),
                              srcs, cand, pen, compute_cost)
         final = np.asarray(f)[:S]

@@ -9,10 +9,12 @@ in milliseconds (``ExecutionEngine.warm_start``).
 
 Lifecycle:
 
-* :func:`enable` — set the cache directory (argument > the standard
-  ``JAX_COMPILATION_CACHE_DIR`` env var > a per-user default) and drop the
-  min-compile-time / min-entry-size thresholds so CPU kernels are cached at
-  all (the defaults assume multi-second accelerator compiles);
+* :func:`enable` — set the cache directory (the standard
+  ``JAX_COMPILATION_CACHE_DIR`` env var > argument > :data:`DEFAULT_DIR`,
+  one fixed directory inside the checkout: the path is part of the cache's
+  key, so a directory that moves never hits) and drop the min-compile-time /
+  min-entry-size thresholds so CPU kernels are cached at all (the defaults
+  assume multi-second accelerator compiles);
 * :func:`disable` — detach the directory (in-memory jit cache untouched);
 * :func:`clear_in_memory` — drop the in-memory executable cache, which is
   exactly what a process restart does: the next compile of the same HLO
@@ -36,7 +38,7 @@ import jax.numpy as jnp
 
 from ..models import cnn
 
-DEFAULT_DIR = Path.home() / ".cache" / "repro-jax-cache"
+DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _reset_backend_cache() -> None:
@@ -44,18 +46,21 @@ def _reset_backend_cache() -> None:
     never re-reads the config afterwards; without this reset, enabling (or
     re-pointing) the cache in a process that already compiled something is
     a silent no-op."""
-    try:
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except (ImportError, AttributeError):   # private API drifted: config
-        pass                                # update alone still covers the
-                                            # enable-before-first-compile path
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 def enable(cache_dir: str | os.PathLike | None = None) -> Path:
-    """Attach the persistent compilation cache; returns the directory."""
-    path = Path(cache_dir if cache_dir is not None
-                else os.environ.get("JAX_COMPILATION_CACHE_DIR", DEFAULT_DIR))
+    """Attach the persistent compilation cache; returns the directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache and no other
+    directory is set here; otherwise ``cache_dir``, else :data:`DEFAULT_DIR`.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return _attach(Path(env or cache_dir or DEFAULT_DIR))
+
+
+def _attach(path: Path) -> Path:
     path.mkdir(parents=True, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", str(path))
     # CPU closures compile in ~0.1–1 s and produce small executables; the
@@ -129,13 +134,15 @@ def measure_warm_start(layer_fns: Sequence[Callable],
     on the disk cache.  ``ranges`` must chain from layer 0 (each start
     produced by an earlier range) so boundary activations can propagate.
 
-    The previously configured cache directory is restored on exit.
+    The previously configured cache directory is restored on exit.  This
+    measures the cache mechanism on the CPU: it attaches ``cache_dir`` itself,
+    even where ``JAX_COMPILATION_CACHE_DIR`` is set.
     """
     ranges = tuple((int(s), int(e)) for s, e in ranges)
     if not ranges or ranges[0][0] != 0:
         raise ValueError(f"ranges must chain from layer 0, got {ranges}")
     prev = jax.config.jax_compilation_cache_dir
-    enable(cache_dir)
+    _attach(Path(cache_dir))
     fns = list(layer_fns)
 
     def build(s: int, e: int) -> Callable:

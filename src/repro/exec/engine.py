@@ -99,7 +99,9 @@ class ExecutionReport:
         """|predicted − executed| per admitted request (requires predicted)."""
         assert self.predicted_s is not None, "report carries no prediction"
         mask = np.isfinite(self.executed_s) & np.isfinite(self.predicted_s)
-        return np.abs(np.where(mask, self.predicted_s - self.executed_s, 0.0))
+        with np.errstate(invalid="ignore"):     # rejected rows: inf - inf
+            return np.abs(np.where(mask, self.predicted_s - self.executed_s,
+                                   0.0))
 
 
 def layer_fns_for(profile: ModelProfile, params=None,

@@ -47,6 +47,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
 
     def kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
         ki = pl.program_id(1)
+        n_valid = len_ref[pl.program_id(0)]
 
         @pl.when(ki == 0)
         def _init():
@@ -59,7 +60,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
         vb = v_ref[0].astype(jnp.float32)
         s = qb @ kb.T                                      # (g, bk)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = k_pos < jnp.minimum(len_ref[0], Smax)
+        valid = k_pos < jnp.minimum(n_valid, Smax)
         s = jnp.where(valid, s, _NEG_INF)
 
         m_prev, l_prev = m_ref[...], l_ref[...]
@@ -75,23 +76,26 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=None,
             o_ref[0] = (acc_ref[...]
                         / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
+    # The per-row lengths ride in SMEM by scalar prefetch: TPU blocks must
+    # tile (8, 128), so a (1,)-block over the lengths vector cannot lower.
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, g, D), lambda b, j: (b, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, g, D), lambda b, j, lens: (b, 0, 0)),
+                pl.BlockSpec((1, block_k, D), lambda b, j, lens: (b, j, 0)),
+                pl.BlockSpec((1, block_k, D), lambda b, j, lens: (b, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, g, D), lambda b, j, lens: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g, D), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, D), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-        ],
         interpret=interpret,
     )(lens_f, qf, kf, vf)
     return out.reshape(B, Hq, D)

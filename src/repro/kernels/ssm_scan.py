@@ -43,7 +43,10 @@ def ssd_scan_pallas(x, a, b, c, h0=None, *, chunk=256, interpret=False):
     bf = b.transpose(0, 2, 1, 3).reshape(B * H, Sp, N)
     cf = c.transpose(0, 2, 1, 3).reshape(B * H, Sp, N)
     la = jnp.log(jnp.maximum(a.astype(jnp.float32), 1e-37))
-    laf = la.transpose(0, 2, 1).reshape(B * H, Sp)
+    # (B*H, G, Q): one head's whole log-decay is one block, so the block's
+    # last two dims equal the array's (TPU blocks tile (8, 128) otherwise);
+    # each grid step reads its chunk's row.
+    laf = la.transpose(0, 2, 1).reshape(B * H, G, Q)
     h_init = (jnp.zeros((B * H, P, N), jnp.float32) if h0 is None
               else h0.astype(jnp.float32).reshape(B * H, P, N))
 
@@ -57,20 +60,28 @@ def ssd_scan_pallas(x, a, b, c, h0=None, *, chunk=256, interpret=False):
         xb = x_ref[0].astype(jnp.float32)            # (Q, P)
         bb = b_ref[0].astype(jnp.float32)            # (Q, N)
         cb = c_ref[0].astype(jnp.float32)
-        lab = la_ref[0].astype(jnp.float32)          # (Q,)
-        cum = jnp.cumsum(lab)                        # logA_t
-        diff = cum[:, None] - cum[None, :]           # (Q, Q) t,s
-        tri = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, diff.shape, 1)
-        gate = jnp.where(tri, jnp.exp(diff), 0.0)
+        la_row = la_ref[0, pl.ds(gi, 1), :]          # (1, Q)
+        # Cumulative log-decay as a column (t) and a row (s), built from
+        # masked 2-D reductions: Mosaic has no 1-D cumsum or transpose here.
+        row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+        tri = row >= col
+        cum_t = jnp.sum(jnp.where(tri, la_row, 0.0), axis=1,
+                        keepdims=True)               # (Q, 1) logA_t
+        la_col = jnp.sum(jnp.where(row == col, la_row, 0.0), axis=1,
+                         keepdims=True)              # (Q, 1)
+        cum_s = jnp.sum(jnp.where(row <= col, la_col, 0.0), axis=0,
+                        keepdims=True)               # (1, Q) logA_s
+        total = jnp.sum(la_row, axis=1, keepdims=True)   # (1, 1) logA_Q
+        gate = jnp.where(tri, jnp.exp(cum_t - cum_s), 0.0)   # (Q, Q) t,s
         dots = cb @ bb.T                             # (Q, Q): c_t · b_s
         y = (dots * gate) @ xb                       # intra-chunk (Q, P)
         h = h_ref[...]                               # (P, N) carried state
-        y = y + jnp.exp(cum)[:, None] * (cb @ h.T)   # inter-chunk
+        y = y + jnp.exp(cum_t) * (cb @ h.T)          # inter-chunk
         y_ref[0] = y.astype(y_ref.dtype)
-        w = jnp.exp(cum[-1] - cum)                   # (Q,)
-        h_inj = xb.T @ (bb * w[:, None])             # (P, N)
-        h_ref[...] = h * jnp.exp(cum[-1]) + h_inj
+        w = jnp.exp(total - cum_t)                   # (Q, 1)
+        h_inj = xb.T @ (bb * w)                      # (P, N)
+        h_ref[...] = h * jnp.exp(total) + h_inj
 
         @pl.when(gi == pl.num_programs(1) - 1)
         def _final():
@@ -83,7 +94,7 @@ def ssd_scan_pallas(x, a, b, c, h0=None, *, chunk=256, interpret=False):
             pl.BlockSpec((1, Q, P), lambda i, g: (i, g, 0)),
             pl.BlockSpec((1, Q, N), lambda i, g: (i, g, 0)),
             pl.BlockSpec((1, Q, N), lambda i, g: (i, g, 0)),
-            pl.BlockSpec((1, Q), lambda i, g: (i, g)),
+            pl.BlockSpec((1, G, Q), lambda i, g: (i, 0, 0)),
             pl.BlockSpec((1, P, N), lambda i, g: (i, 0, 0)),
         ],
         out_specs=[

@@ -47,11 +47,6 @@ def cell_supported(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
     return True, ""
 
 
-def production_cfg(cfg: ModelConfig) -> ModelConfig:
-    return dataclasses.replace(cfg, param_dtype="bfloat16",
-                               compute_dtype="bfloat16")
-
-
 # ---------------------------------------------------------------------------
 # input specs (ShapeDtypeStructs — never allocated)
 # ---------------------------------------------------------------------------
@@ -255,7 +250,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     loop's knob (chunk sizes, capacity factors, …).  ``tag`` suffixes the
     artifact name so optimized variants never overwrite the paper-faithful
     baseline artifacts."""
-    cfg = production_cfg(C.get_config(arch))
+    cfg = C.get_config(arch).production()
     if cfg_transform is not None:
         cfg = cfg_transform(cfg)
     shape = SHAPES[shape_name]

@@ -1,19 +1,53 @@
 """Serving launcher: batched greedy generation with the production server
-(prefill + donated-cache decode), reduced config on CPU — plus request
+(prefill + donated-cache decode), reduced config by default or the full
+config at its production dtypes with ``--full-width`` — plus request
 placement over the serving pool via any registered planner.
 
     PYTHONPATH=src python -m repro.launch.serve --arch yi_6b --steps 16 \\
         --planner ould-dp --pool-nodes 8
+
+The persistent compile cache is always on (``repro.exec.compile_cache``:
+``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
+from typing import Any
+
+import numpy as np
+
+# Per-node memory of the executed CNN pool, sized from the profile.  LeNet
+# (~108 MB) at 128 MB: co-sourced requests must offload part of their path.
+# VGG16 (~1.0 GB, its head unit alone ~480 MB) at the paper's high memory
+# level, 512 MB: every request splits over several nodes.
+NODE_MEM_BYTES = {"lenet": 128e6, "vgg16": 512e6}
 
 
-def main() -> None:
+@dataclasses.dataclass
+class ServeRun:
+    """What one :func:`main` call served, for scripts that check it."""
+
+    server: Any                   # runtime.serve.Server
+    prompts: np.ndarray           # (batch, prompt_len) token ids
+    generated: np.ndarray         # (batch, steps) greedy token ids
+    # --execute only: the placed CNN round and its engine
+    engine: Any = None
+    frames: np.ndarray | None = None
+    cnn_plan: Any = None
+    graph: Any = None
+    report: Any = None
+
+
+def main(argv: list[str] | None = None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2_1p8b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the config at full size with bf16 params "
+                         "and compute (default: a 2-layer, d_model=128 "
+                         "reduction in f32)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--steps", type=int, default=16)
@@ -28,6 +62,8 @@ def main() -> None:
                     help="run a placed CNN inference through the repro.exec "
                          "engine and report predicted vs measured latency "
                          "(plus a calibrated re-solve)")
+    ap.add_argument("--model", default="lenet", choices=sorted(NODE_MEM_BYTES),
+                    help="CNN placed and executed under --execute")
     ap.add_argument("--transport", default="inproc",
                     choices=("inproc", "loopback", "multiproc"),
                     help="byte-moving backend for --execute transfers: "
@@ -37,16 +73,12 @@ def main() -> None:
                          "also calibrate the rates from realized bandwidth "
                          "before the re-solve")
     ap.add_argument("--transport-workers", type=int, default=2)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(repro.exec.compile_cache): repeat runs and "
-                         "rejoining nodes warm from disk")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Chrome/Perfetto-loadable trace of this "
                          "run (repro.obs): solver/admission spans for the "
                          "pool placement, engine stage walls and transport "
                          "shipments under --execute")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     tracer = metrics = None
     if args.trace_out:
@@ -55,15 +87,19 @@ def main() -> None:
         metrics = MetricsRegistry()
 
     import jax
-    import numpy as np
 
     import repro.configs as C
     from repro.core.radio import TpuLinkModel
+    from repro.exec import compile_cache
     from repro.models import init_params
     from repro.runtime.serve import ServeConfig, Server, schedule_requests
 
-    cfg = C.get_config(args.arch).reduced(n_layers=2, d_model=128, vocab=1024)
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    compile_cache.enable()
+    cfg = C.get_config(args.arch)
+    cfg = (cfg.production() if args.full_width
+           else cfg.reduced(n_layers=2, d_model=128, vocab=1024))
+    params = jax.jit(functools.partial(init_params, cfg=cfg))(
+        jax.random.PRNGKey(0))
     srv = Server(cfg, params, ServeConfig(
         max_len=args.prompt_len + args.steps + 1, batch_size=args.batch))
     prompts = np.random.default_rng(0).integers(
@@ -94,6 +130,7 @@ def main() -> None:
           f"comm={ev.comm_latency_s * 1e6:.1f}us "
           f"stages(req0)={len(plan.stages(0)) if plan.admitted[0] else 0}"
           + sparse)
+    run = ServeRun(srv, prompts, out)
 
     if args.execute:
         # Plan-faithful execution: place the paper's CNN over the same pool
@@ -102,21 +139,20 @@ def main() -> None:
         # measured-calibrated profile — and, with a byte-moving transport,
         # on realized link bandwidth too (DESIGN.md §5/§7).
         from repro.core import (Problem, SnapshotView, get_planner,
-                                lenet_profile)
+                                lenet_profile, vgg16_profile)
         from repro.exec import (ExecutionEngine, calibrated_problem,
-                                compile_cache, compile_plan, layer_fns_for)
+                                compile_plan, layer_fns_for)
         from repro.transport import make_transport
 
-        if args.compile_cache:
-            compile_cache.enable(args.compile_cache)
-        profile = lenet_profile()
+        profile = {"lenet": lenet_profile, "vgg16": vgg16_profile}[
+            args.model]()
         rng = np.random.default_rng(0)
-        # Hotspot the frames on two camera nodes: lenet wants ~108 MB end to
-        # end, so at 128 MB/node the co-sourced requests must offload part of
-        # their path — the plan has transfers for the transport to carry.
+        # Hotspot the frames on two camera nodes; at NODE_MEM_BYTES the plan
+        # has transfers for the transport to carry.
         sources = (np.arange(args.batch) % min(2, n)).astype(np.int64)
-        prob = Problem(profile, np.full(n, 128e6), np.full(n, 95e9),
-                       rates_bits, sources, compute_speed=np.full(n, 9.5e9))
+        prob = Problem(profile, np.full(n, NODE_MEM_BYTES[args.model]),
+                       np.full(n, 95e9), rates_bits, sources,
+                       compute_speed=np.full(n, 9.5e9))
         if tracer is not None:
             # Route placement through the controller so the trace carries
             # the solver span + per-request admission verdicts.
@@ -160,9 +196,12 @@ def main() -> None:
                             tracer.now() - t_round, args=trace_args(regraph))
         finally:
             transport.close()
+        run.engine, run.frames, run.cnn_plan = engine, frames, cnn_plan
+        run.graph, run.report = graph, report
         mae0 = report.abs_error_s[list(report.outputs)].mean()
         mae1 = rereport.abs_error_s[list(rereport.outputs)].mean()
-        print(f"[exec] tasks={len(graph.tasks)} shared={graph.n_shared} "
+        print(f"[exec] model={args.model} admitted={cnn_plan.n_admitted}/"
+              f"{args.batch} tasks={len(graph.tasks)} shared={graph.n_shared} "
               f"transfers={len(graph.transfers)} "
               f"executed_avg={report.executed_s[list(report.outputs)].mean():.4f}s")
         print(f"[exec] {recon.summary()}")
@@ -198,6 +237,7 @@ def main() -> None:
             print("[trace] metrics: " + ", ".join(
                 f"{k}={v:.4g}" if isinstance(v, float) else f"{k}={v}"
                 for k, v in snap.items() if not isinstance(v, dict)))
+    return run
 
 
 if __name__ == "__main__":

@@ -123,7 +123,6 @@ SHARD_MAP_MIN_TOKENS = 16_384  # below this, GSPMD token-movement wins
 
 
 def _moe_shard_map(p: dict, cfg: ModelConfig, x2: jax.Array, mesh, axes):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -182,12 +181,12 @@ def _moe_shard_map(p: dict, cfg: ModelConfig, x2: jax.Array, mesh, axes):
         aux = jax.lax.pmean(aux, axes.dp)
         return y, aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(axes.model, dp, None), P(axes.model, dp, None),
                   P(axes.model, None, dp), P(dp, None)),
         out_specs=(P(dp, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(p["router"], p["w_gate"], p["w_in"], p["w_out"], x2)
     return y, aux
 

@@ -219,6 +219,13 @@ def param_shapes(cfg: ModelConfig) -> Any:
                           jax.random.PRNGKey(0))
 
 
+def _carry(cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """The residual stream between layer groups stays in the compute dtype:
+    with f32 params a bf16 carry comes out of the blocks' matmuls as f32, and
+    a scan carry may not change dtype."""
+    return x.astype(dtype_of(cfg.compute_dtype))
+
+
 def _embed_in(params, cfg: ModelConfig, batch: dict) -> jax.Array:
     if "embeds" in batch:
         x = batch["embeds"].astype(dtype_of(cfg.compute_dtype))
@@ -255,7 +262,7 @@ def forward(params: dict, cfg: ModelConfig, batch: dict,
         for pp, kind in enumerate(pat):
             x, a = _block_apply(block_slices[pp], cfg, kind, x, positions)
             aux = aux + a
-        return x, aux
+        return _carry(cfg, x), aux
 
     if remat:
         body = jax.checkpoint(body)
@@ -338,7 +345,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
             x, c = _block_decode(block_slices[pp], cfg, kind, x,
                                  cache_slices[pp], pos)
             new_caches.append(c)
-        return x, tuple(new_caches)
+        return _carry(cfg, x), tuple(new_caches)
 
     _, groups = _pattern(cfg)
     x, new_cache = jax.lax.scan(scan_body, x,
@@ -407,7 +414,7 @@ def prefill(params: dict, cfg: ModelConfig, batch: dict,
                 x = x + y
             x = with_dp_constraint(x)
             caches.append(c)
-        return x, tuple(caches)
+        return _carry(cfg, x), tuple(caches)
 
     x, cache = jax.lax.scan(scan_body, x, tuple(params["blocks"]),
                             unroll=_unroll(groups))

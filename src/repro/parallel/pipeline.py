@@ -27,7 +27,6 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -133,9 +132,9 @@ def pipeline_forward_stages(block_fn: Callable, params_stacked, x, *,
             stage_axis)
         return out.reshape(B, *x_all.shape[1:])
 
-    fn = shard_map(stage_fn, mesh=mesh,
-                   in_specs=(P(stage_axis), P(), P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(stage_fn, mesh=mesh,
+                       in_specs=(P(stage_axis), P(), P()),
+                       out_specs=P(), check_vma=False)
     return fn(padded, sizes_arr, x)
 
 

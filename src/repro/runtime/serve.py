@@ -38,23 +38,25 @@ class Server:
         self.cfg = cfg
         self.scfg = scfg
         self.params = params
-        self._prefill = jax.jit(steps_mod.make_prefill_step(
+        # The jitted steps: (params, batch) -> (logits, cache) and
+        # (params, tokens, cache, pos) -> (logits, cache), cache donated.
+        self.prefill = jax.jit(steps_mod.make_prefill_step(
             cfg, max_len=scfg.max_len))
-        self._decode = jax.jit(steps_mod.make_decode_step(cfg),
-                               donate_argnums=(2,))
+        self.decode = jax.jit(steps_mod.make_decode_step(cfg),
+                              donate_argnums=(2,))
 
     def generate(self, tokens: np.ndarray, steps: int) -> np.ndarray:
         """tokens: (B, S) prompt → (B, steps) generated ids (greedy)."""
         B, S = tokens.shape
         assert S + steps <= self.scfg.max_len
-        logits, cache = self._prefill(self.params,
+        logits, cache = self.prefill(self.params,
                                       {"tokens": jnp.asarray(tokens)})
         out = []
         pos = jnp.int32(S)
         tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
         for _ in range(steps):
             out.append(np.asarray(tok[:, 0]))
-            logits, cache = self._decode(self.params, tok, cache, pos)
+            logits, cache = self.decode(self.params, tok, cache, pos)
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
             pos = pos + 1
         return np.stack(out, axis=1)

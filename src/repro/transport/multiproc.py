@@ -15,6 +15,7 @@ Node → process ownership follows the swarm's mobility groups when a
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from .loopback import LoopbackTransport
@@ -35,3 +36,14 @@ class MultiProcTransport(LoopbackTransport):
             node_of = {int(i): int(g) for i, g in enumerate(group_of)}
         super().__init__(n_workers=n_workers or 2, node_of=node_of,
                          timeout_s=timeout_s)
+
+    def start(self) -> None:
+        # A chip belongs to one process: this parent holds it, so a JAX
+        # worker could never get it and the parent would wait out its
+        # timeout.  Nodes as devices of one process is ROADMAP R8.
+        if not self.started and jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "multiproc transport spawns JAX worker processes, but this "
+                "process holds the TPU and a chip serves one process; use "
+                "the inproc or loopback transport on a chip host")
+        super().start()

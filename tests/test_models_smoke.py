@@ -127,3 +127,24 @@ def test_cnn_lenet_vgg_forward():
     out2 = cnn.apply_layers(cnn.vgg16_layers(vp), mid, 9)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2), rtol=1e-5,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_full_width_traces_at_config_dtypes(step):
+    """Full-width internlm2_1p8b at the config's own dtypes (f32 params,
+    bf16 compute): the layer scan's carry keeps its dtype.  Shapes only."""
+    cfg = C.get_config("internlm2_1p8b")
+    assert (cfg.param_dtype, cfg.compute_dtype) == ("float32", "bfloat16")
+    params = jax.eval_shape(lambda k: init_params(k, cfg), KEY)
+    B, S, max_len = 4, 128, 145
+    toks = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    logits, cache = jax.eval_shape(
+        lambda p, t: prefill(p, cfg, {"tokens": t}, max_len=max_len),
+        params, toks)
+    if step == "decode":
+        logits, cache = jax.eval_shape(
+            lambda p, t, c, n: decode_step(p, cfg, t, c, n), params,
+            jax.ShapeDtypeStruct((B, 1), jnp.int32), cache,
+            jax.ShapeDtypeStruct((), jnp.int32))
+    assert logits.shape == (B, cfg.vocab) and logits.dtype == jnp.float32
+    assert cache[0]["k"].shape == (cfg.n_layers, B, max_len, cfg.n_kv, cfg.hd)

@@ -1,15 +1,22 @@
 """End-to-end behaviour: the paper's scenario executed for real (placed CNN
 inference over a simulated swarm) and placement↔sharding integration."""
 
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import numpy as np
 
-from repro.core import (Problem, evaluate, lenet_profile, solve_ould,
-                        to_stages)
+from repro.core import (Problem, SnapshotView, evaluate, get_planner,
+                        lenet_profile, solve_ould, to_stages, vgg16_profile)
 from repro.core.mobility import RPGMobility, RPGParams
 from repro.core.placement import balanced_stages, ould_pipeline_stages
 from repro.core.profiles import lm_profile
 from repro.core.radio import RadioParams, TpuLinkModel, rate_matrix
+from repro.exec import compile_cache, compile_plan
+from repro.launch import serve
 from repro.models import cnn
 
 MB = 1e6
@@ -82,3 +89,57 @@ def test_balanced_stages_flops_balance():
     per_stage = [sum(flops[s.layer_start:s.layer_end]) for s in stages]
     assert len(stages) == 4
     assert max(per_stage) / max(min(per_stage), 1.0) < 3.0
+
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    """No chip: a non-zero exit, a message, and no result line."""
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, str(ROOT / "chip_smoke.py")],
+                         cwd=ROOT, env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode != 0
+    assert "needs a TPU" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+def test_serve_main_executes_the_placed_cnn():
+    """The CLI entry point, called with argv, serves the LM and runs the
+    placed LeNet round whose outputs equal the one-node reference."""
+    prev = compile_cache.cache_dir()
+    try:
+        run = serve.main(["--prompt-len", "8", "--steps", "3",
+                          "--planner", "ould-dp-sparse", "--execute"])
+    finally:                  # main turns the persistent cache on
+        if prev is None:
+            compile_cache.disable()
+        else:
+            compile_cache.enable(prev)
+    assert run.generated.shape == (4, 3)
+    admitted = np.flatnonzero(run.cnn_plan.admitted)
+    assert admitted.size and run.graph.transfers
+    ref = run.engine.sequential_reference(run.frames, list(admitted))
+    for r in admitted:
+        np.testing.assert_allclose(run.report.outputs[r], ref[r],
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_vgg16_pool_memory_forces_a_split():
+    """At the serve pool's VGG16 node memory (the paper's 512 MB level) the
+    ~1 GB model is admitted only split over nodes, with transfers."""
+    n = 8
+    link = TpuLinkModel()
+    coords = np.stack([np.arange(n) % link.torus[0],
+                       np.arange(n) // link.torus[0]], -1)
+    rates = link.rate_matrix(coords, np.zeros(n, np.int64)) * 8.0
+    prob = Problem(vgg16_profile(), np.full(n, serve.NODE_MEM_BYTES["vgg16"]),
+                   np.full(n, 95e9), rates, np.array([0, 1, 0, 1]),
+                   compute_speed=np.full(n, 9.5e9))
+    plan = get_planner("ould-dp-sparse").plan(prob, SnapshotView(rates))
+    assert plan.n_admitted >= 1
+    for r in np.flatnonzero(plan.admitted):
+        assert len(set(plan.assign[r].tolist())) >= 2
+    assert compile_plan(plan).transfers

@@ -3,6 +3,7 @@ engine equivalence across backends, persistent-compile-cache warm starts,
 and the bandwidth-calibrated re-solve loop."""
 
 import os
+import pathlib
 
 import jax
 import numpy as np
@@ -210,6 +211,42 @@ def test_compile_cache_enable_restores(tmp_path):
         else:
             compile_cache.enable(prev)
     assert compile_cache.cache_dir() == prev
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_dir_env_over_argument(tmp_path, monkeypatch, env_set):
+    """JAX_COMPILATION_CACHE_DIR is the cache whenever it is set; without
+    it an argument wins, and with neither the fixed in-checkout path."""
+    prev = compile_cache.cache_dir()
+    env = tmp_path / "from_env"
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable(tmp_path / "from_arg")
+        assert got == (env if env_set else tmp_path / "from_arg")
+        default = compile_cache.enable()
+        assert default == (env if env_set else compile_cache.DEFAULT_DIR)
+        assert compile_cache.cache_dir() == default
+    finally:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        if prev is None:
+            compile_cache.disable()
+        else:
+            compile_cache.enable(prev)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.DEFAULT_DIR == root / ".jax_cache"
+
+
+def test_multiproc_refuses_a_tpu_parent(monkeypatch):
+    """A chip serves one process: under a TPU parent the JAX workers could
+    never get it, so start() refuses before spawning anything."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tp = MultiProcTransport(n_workers=2)
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        tp.start()
+    assert not tp._procs and not tp.started
 
 
 def test_engine_warm_start_compiles_signature():
