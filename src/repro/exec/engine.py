@@ -127,6 +127,22 @@ def layer_fns_for(profile: ModelProfile, params=None,
     return fns
 
 
+# Arg labels of the engine's spans (DESIGN.md §9).
+_SPAN_ARGS = {
+    "run": ("requests", "tasks"),
+    "upload": ("bytes",),
+    "gather": ("rows",),
+    "compile": ("layer_start", "layer_end"),
+    "launch": ("batch", "units"),
+    "dispatch": (),
+    "split": ("rows",),
+    "fetch": ("bytes",),
+    "done": (),
+    "stage_measure": ("layer_start", "layer_end"),
+    "warm_start": ("n_ranges",),
+}
+
+
 class ExecutionEngine:
     """Executes stage graphs over one model's ``layer_fns``.
 
@@ -142,15 +158,14 @@ class ExecutionEngine:
         self.mesh = mesh
         self.data_axis = data_axis
         self.transport = transport if transport is not None else InProcTransport()
-        # Observability: engine spans are real-time (``tracer.now()``) and
-        # reconstructed from the measured walls the engine takes anyway —
-        # nothing is timed inside the jitted closures.  Transfer spans come
-        # from the transport itself (single emission point in _record).
+        # Observability: live real-time spans (``Tracer.scope``) around the
+        # host work of a run, each also a profiler annotation; nothing is
+        # timed inside the jitted closures.  Transfer spans come from the
+        # transport itself.
         self.tracer = tracer if tracer is not None else NULL_TRACER
         if self.tracer.enabled:
-            self.tracer.intern("stage", "batch", "n_layers")
-            self.tracer.intern("stage_measure", "layer_start", "layer_end")
-            self.tracer.intern("warm_start", "n_ranges")
+            for name, labels in _SPAN_ARGS.items():
+                self.tracer.intern(name, *labels)
             set_tr = getattr(self.transport, "set_tracer", None)
             if set_tr is not None:
                 set_tr(self.tracer)
@@ -193,14 +208,12 @@ class ExecutionEngine:
             jax.block_until_ready(fn(x))
             self._warm.add(warm_key)
         best = float("inf")
-        for _ in range(max(1, repeats)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(x))
-            best = min(best, time.perf_counter() - t0)
-        if self.tracer.enabled:
-            self.tracer.span(ENGINE, "stage_measure",
-                             self.tracer.now() - best, best,
-                             a0=layer_start, a1=layer_end)
+        with self.tracer.scope(ENGINE, "stage_measure", a0=layer_start,
+                               a1=layer_end):
+            for _ in range(max(1, repeats)):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(x))
+                best = min(best, time.perf_counter() - t0)
         return best
 
     def warm_start(self, signature: Sequence[tuple[int, int]],
@@ -217,84 +230,106 @@ class ExecutionEngine:
         produced is fed through a ``[0, start)`` prefix closure.
         """
         t_begin = time.perf_counter()
-        acts: dict[int, jax.Array] = {0: jnp.asarray(frame[None])}
-        for s, e in sorted(signature):
-            if s not in acts:
-                acts[s] = self.closure(0, s)(acts[0])
-            acts[e] = jax.block_until_ready(self.closure(s, e)(acts[s]))
-            self._warm.add((s, e, tuple(acts[s].shape)))
-        wall = time.perf_counter() - t_begin
-        if self.tracer.enabled:
-            self.tracer.span(ENGINE, "warm_start",
-                             self.tracer.now() - wall, wall,
-                             a0=len(signature))
-        return wall
+        with self.tracer.scope(ENGINE, "warm_start", a0=len(signature)):
+            acts: dict[int, jax.Array] = {0: jnp.asarray(frame[None])}
+            for s, e in sorted(signature):
+                if s not in acts:
+                    acts[s] = self.closure(0, s)(acts[0])
+                acts[e] = jax.block_until_ready(self.closure(s, e)(acts[s]))
+                self._warm.add((s, e, tuple(acts[s].shape)))
+        return time.perf_counter() - t_begin
 
     def _launch(self, task: StageTask, x: jax.Array) -> tuple[jax.Array, float]:
-        """Run one batched stage; returns (output, measured wall seconds)."""
+        """Run one batched stage; returns (output, measured wall seconds).
+        The wall is the ``launch`` span's: from the call of the closure to
+        the output ready on the device; its ``dispatch`` child ends when the
+        call returns (the enqueue), the rest is the host blocked."""
+        tr = self.tracer
         fn = self.closure(task.layer_start, task.layer_end)
         x = self._device_put(x)
         warm_key = (task.layer_start, task.layer_end, tuple(x.shape))
         if warm_key not in self._warm:        # compile outside the clock
-            jax.block_until_ready(fn(x))
+            with tr.scope(ENGINE, "compile", lane=task.node,
+                          a0=task.layer_start, a1=task.layer_end):
+                jax.block_until_ready(fn(x))
             self._warm.add(warm_key)
-        t0 = time.perf_counter()
-        y = jax.block_until_ready(fn(x))
-        return y, time.perf_counter() - t0
+        with tr.scope(ENGINE, "launch", lane=task.node,
+                      a0=len(task.requests),
+                      a1=task.layer_end - task.layer_start) as span:
+            t0 = time.perf_counter()
+            with tr.scope(ENGINE, "dispatch", lane=task.node):
+                y = fn(x)
+            y = jax.block_until_ready(y)
+            t1 = time.perf_counter()
+            span.interval(t0, t1)
+        return y, t1 - t0
 
     # -- execution -----------------------------------------------------------
     def run(self, graph: StageGraph, frames: np.ndarray, *,
             predicted_s: np.ndarray | None = None) -> ExecutionReport:
         """Execute ``graph`` on ``frames`` (one leading row per plan request;
         rejected rows are never read).  Returns the full measured report."""
-        acts: dict[int, jax.Array] = {
-            r: jnp.asarray(frames[r][None]) for r in graph.requests}
-        timings: list[StageTiming] = []
-        compute_s = np.zeros(graph.n_requests)
+        tr = self.tracer
+        with tr.scope(ENGINE, "run", a0=len(graph.requests),
+                      a1=len(graph.tasks)):
+            with tr.scope(ENGINE, "upload", a0=sum(
+                    frames[r].nbytes for r in graph.requests)):
+                acts: dict[int, jax.Array] = {
+                    r: jnp.asarray(frames[r][None]) for r in graph.requests}
+            timings: list[StageTiming] = []
+            compute_s = np.zeros(graph.n_requests)
 
-        transfer_by_consumer = {(tr.request, tr.layer): tr
-                                for tr in graph.transfers}
-        records: list[TransferRecord] = []
+            transfer_by_consumer = {(link.request, link.layer): link
+                                    for link in graph.transfers}
+            records: list[TransferRecord] = []
+            # task index of each request's last launch, for its `done`
+            last = ({r: i for i, t in enumerate(graph.tasks)
+                     for r in t.requests} if tr.enabled else {})
 
-        for task in graph.tasks:
-            # Boundary shipments INTO this stage ride the transport backend:
-            # inproc measures the host serialization of the inbound
-            # activation; loopback/multiproc move its bytes to the worker
-            # process owning the destination node and the consuming stage
-            # reads what came back.
-            for r in task.requests:
-                tr = transfer_by_consumer.get((r, task.layer_start))
-                if tr is None:
-                    continue
-                res = self.transport.ship(tr.src_node, tr.dst_node, acts[r])
-                acts[r] = res.array
-                records.append(TransferRecord(
-                    tr.request, tr.src_node, tr.dst_node, tr.layer,
-                    tr.nbytes, tr.delay_s, res.wall_s))
-            x = (acts[task.requests[0]] if len(task.requests) == 1
-                 else jnp.concatenate([acts[r] for r in task.requests]))
-            y, wall = self._launch(task, x)
-            timings.append(StageTiming(task.node, task.layer_start,
-                                       task.layer_end, len(task.requests),
-                                       wall))
-            if self.tracer.enabled:
-                # ts backdated by the measured wall so the span covers the
-                # timed run, never the compile _launch keeps off the clock.
-                self.tracer.span(ENGINE, "stage",
-                                 self.tracer.now() - wall, wall,
-                                 lane=task.node, a0=len(task.requests),
-                                 a1=task.layer_end - task.layer_start)
-            for b, r in enumerate(task.requests):
-                acts[r] = y[b][None]
-                compute_s[r] += wall
+            for i, task in enumerate(graph.tasks):
+                # Boundary shipments INTO this stage ride the transport
+                # backend: inproc measures the host serialization of the
+                # inbound activation; loopback/multiproc move its bytes to
+                # the worker process owning the destination node and the
+                # consuming stage reads what came back.
+                for r in task.requests:
+                    link = transfer_by_consumer.get((r, task.layer_start))
+                    if link is None:
+                        continue
+                    res = self.transport.ship(link.src_node, link.dst_node,
+                                              acts[r])
+                    acts[r] = res.array
+                    records.append(TransferRecord(
+                        link.request, link.src_node, link.dst_node,
+                        link.layer, link.nbytes, link.delay_s, res.wall_s))
+                if len(task.requests) == 1:
+                    x = acts[task.requests[0]]
+                else:
+                    with tr.scope(ENGINE, "gather", a0=len(task.requests)):
+                        x = jnp.concatenate([acts[r] for r in task.requests])
+                y, wall = self._launch(task, x)
+                timings.append(StageTiming(task.node, task.layer_start,
+                                           task.layer_end, len(task.requests),
+                                           wall))
+                if tr.enabled:
+                    now = tr.now()
+                    for r in task.requests:
+                        if last[r] == i:
+                            tr.instant(ENGINE, "done", now, frame=r)
+                with tr.scope(ENGINE, "split", a0=len(task.requests)):
+                    for b, r in enumerate(task.requests):
+                        acts[r] = y[b][None]
+                        compute_s[r] += wall
 
-        comm_s = np.zeros(graph.n_requests)
-        for tr in graph.transfers:
-            comm_s[tr.request] += tr.delay_s
-        executed = np.full(graph.n_requests, np.inf)
-        for r in graph.requests:
-            executed[r] = compute_s[r] + comm_s[r]
-        outputs = {r: np.asarray(acts[r][0]) for r in graph.requests}
+            comm_s = np.zeros(graph.n_requests)
+            for link in graph.transfers:
+                comm_s[link.request] += link.delay_s
+            executed = np.full(graph.n_requests, np.inf)
+            for r in graph.requests:
+                executed[r] = compute_s[r] + comm_s[r]
+            with tr.scope(ENGINE, "fetch") as span:
+                outputs = {r: np.asarray(acts[r][0]) for r in graph.requests}
+                span.set(a0=sum(o.nbytes for o in outputs.values()))
         return ExecutionReport(outputs, tuple(timings), tuple(records),
                                executed, compute_s, comm_s, predicted_s,
                                transport=self.transport.name)

@@ -190,18 +190,6 @@ def stage_signature(graph: StageGraph) -> tuple[tuple[int, int], ...]:
     return tuple(sorted({(t.layer_start, t.layer_end) for t in graph.tasks}))
 
 
-def trace_args(graph: StageGraph) -> dict:
-    """Summarize a graph for a rich (dict-args) trace span — the low-rate
-    annotation the CLI attaches to its per-round execution span, so a
-    Perfetto click on the round shows what actually launched."""
-    return {"n_tasks": len(graph.tasks),
-            "n_transfers": len(graph.transfers),
-            "n_requests": len(graph.requests),
-            "n_shared": graph.n_shared,
-            "transfer_bytes": float(sum(tr.nbytes for tr in graph.transfers)),
-            "signature": [list(rng) for rng in stage_signature(graph)]}
-
-
 def link_payload_bytes(graph: StageGraph) -> dict[tuple[int, int], float]:
     """Total modeled bytes each directed link carries for this graph — the
     coverage map of a comm calibration: links listed here are the ones a

@@ -76,8 +76,9 @@ def main(argv: list[str] | None = None) -> ServeRun:
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Chrome/Perfetto-loadable trace of this "
                          "run (repro.obs): solver/admission spans for the "
-                         "pool placement, engine stage walls and transport "
-                         "shipments under --execute")
+                         "pool placement; under --execute, live engine.run "
+                         "spans with their launches and transport "
+                         "shipments")
     args = ap.parse_args(argv)
 
     tracer = metrics = None
@@ -172,28 +173,16 @@ def main(argv: list[str] | None = None) -> ServeRun:
         frames = rng.standard_normal(
             (args.batch, 326, 595, 3)).astype(np.float32)
         try:
-            if tracer is not None:
-                from repro.exec.stage_graph import trace_args
-                from repro.obs import ENGINE
-                t_round = tracer.now()
             report = engine.run(graph, frames,
                                 predicted_s=cnn_plan.evaluate().per_request_s)
-            if tracer is not None:
-                tracer.span(ENGINE, "execute_round", t_round,
-                            tracer.now() - t_round, args=trace_args(graph))
             moving = args.transport != "inproc"
             cal_prob, recon = calibrated_problem(
                 prob, report, transport=transport if moving else None)
             replan = get_planner(args.planner, sparse_k=args.sparse_k).plan(
                 cal_prob, SnapshotView(cal_prob.rates))
             regraph = compile_plan(replan)
-            if tracer is not None:
-                t_round = tracer.now()
             rereport = engine.run(regraph, frames,
                                   predicted_s=replan.evaluate().per_request_s)
-            if tracer is not None:
-                tracer.span(ENGINE, "execute_recal", t_round,
-                            tracer.now() - t_round, args=trace_args(regraph))
         finally:
             transport.close()
         run.engine, run.frames, run.cnn_plan = engine, frames, cnn_plan

@@ -133,8 +133,11 @@ def vgg16_layers(params: dict) -> list[Callable]:
 
 def apply_layers(layer_fns: list[Callable], x: jax.Array,
                  start: int = 0, end: int | None = None) -> jax.Array:
-    """Run units [start, end) — the placed-inference execution primitive."""
+    """Run units [start, end) — the placed-inference execution primitive.
+    Each unit runs under ``jax.named_scope(f"unit{i}")`` (op metadata only),
+    so a device trace can put its ops' time down to the unit."""
     end = end if end is not None else len(layer_fns)
-    for fn in layer_fns[start:end]:
-        x = fn(x)
+    for i in range(start, end):
+        with jax.named_scope(f"unit{i}"):
+            x = layer_fns[i](x)
     return x

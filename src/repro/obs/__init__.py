@@ -1,11 +1,11 @@
 """End-to-end observability for the serving path (`repro.obs`) — DESIGN §9.
 
-Two pieces, both designed around the "reconstruct from kernel outputs,
-never instrument inside jit" rule:
+Two pieces, both designed around the "never instrument inside jit" rule:
 
 * :mod:`repro.obs.tracer` — a bounded flight-recorder :class:`Tracer`
   (numpy struct-of-arrays ring buffer, span/instant events, vectorized
-  batch appends) exporting Chrome trace-event JSON loadable in Perfetto;
+  batch appends, live ``scope`` spans that are also profiler annotations)
+  exporting Chrome trace-event JSON loadable in Perfetto;
   :class:`NullTracer` is the default, so the traced-off path is free.
 * :mod:`repro.obs.metrics` — a :class:`MetricsRegistry` of counters,
   gauges, and fixed-bucket histograms behind one ``snapshot() -> dict``,

@@ -17,13 +17,17 @@ Two contracts keep the overhead honest:
 * **The default is off.**  :class:`NullTracer` implements the same API as
   no-ops; every traced call site guards bulk argument preparation with
   ``tracer.enabled``, so the traced-off serving path is bit-identical to
-  the pre-tracing code and costs ~one attribute check per window.
-* **Reconstruct from kernel outputs, never instrument inside jit.**  The
-  vectorized queue advance, the jitted DP dispatch, and the stage closures
-  are never modified to emit events mid-kernel; callers rebuild each
-  frame's spans *post hoc* from the arrays those kernels already return
-  (Lindley start/finish, ``ResolveStats``, measured stage walls).  Tracing
-  therefore cannot perturb the numbers it reports.
+  the pre-tracing code and costs one no-op call per span (a shared
+  no-op context for ``scope``).
+* **Never instrument inside jit.**  The vectorized queue advance, the
+  jitted DP dispatch, and the stage closures are never modified to emit
+  events mid-kernel.  Simulated-time callers rebuild each frame's spans
+  *post hoc* from the arrays those kernels already return (Lindley
+  start/finish, ``ResolveStats``); real-time callers (engine, transport,
+  admission) time the host work around the kernels *live* with
+  :meth:`Tracer.scope`, whose span is also a ``jax.profiler``
+  annotation named ``repro.<track>.<name>``, so it lands in a profiler
+  trace on the device trace's clock.
 
 ``export_chrome(path)`` writes the Chrome trace-event JSON array format —
 loadable in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing`` —
@@ -39,6 +43,7 @@ import math
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # Pre-registered subsystem tracks (Chrome pid).  New subsystems register
 # theirs via ``Tracer.track(name)`` — codes are allocated in call order.
@@ -104,6 +109,7 @@ class Tracer:
         self._tracks: list[str] = list(_BUILTIN_TRACKS)
         self._track_ids = {t: i for i, t in enumerate(self._tracks)}
         self._rich: dict[int, dict] = {}   # abs seq -> args dict (low-rate)
+        self._scopes: dict[tuple[int, str], tuple[int, str]] = {}
         self._t0 = time.perf_counter()     # origin of the real-time clock
 
     # -- clock --------------------------------------------------------------
@@ -164,6 +170,24 @@ class Tracer:
         """One point event (Chrome instant event, phase ``i``)."""
         self.span(track, name, ts, _INSTANT, lane=lane, frame=frame,
                   a0=a0, a1=a1, args=args)
+
+    def scope(self, track: int, name: str, *, lane: int = 0,
+              frame: int = -1, a0: float = math.nan,
+              a1: float = math.nan) -> "_Scope":
+        """A live span around a ``with`` block on the real-time clock.
+
+        The block is timed as it runs and one span is written when it
+        exits; for the same interval a ``jax.profiler.TraceAnnotation``
+        named ``repro.<track>.<name>`` is held, so the span also lands in
+        any active profiler trace, on the device trace's clock.  Args known
+        only at the end (bytes, verdicts) go through the yielded handle's
+        :meth:`_Scope.set`."""
+        key = (track, name)
+        hit = self._scopes.get(key)
+        if hit is None:
+            hit = self._scopes[key] = (
+                self.intern(name), f"repro.{self._tracks[track]}.{name}")
+        return _Scope(self, track, hit[0], hit[1], lane, frame, a0, a1)
 
     # -- vectorized emit ----------------------------------------------------
     def _append_batch(self, track: int, nid: int, ts, dur, lane, frame,
@@ -309,6 +333,73 @@ class Tracer:
         return len(out)
 
 
+class _Scope:
+    """The handle :meth:`Tracer.scope` yields: one live span."""
+
+    __slots__ = ("_tr", "_track", "_nid", "_ann", "_lane", "_frame", "_a0",
+                 "_a1", "_args", "_t0", "_t1")
+
+    def __init__(self, tr: Tracer, track: int, nid: int, label: str,
+                 lane: int, frame: int, a0: float, a1: float):
+        self._tr, self._track, self._nid = tr, track, nid
+        self._ann = TraceAnnotation(label)
+        self._lane, self._frame, self._a0, self._a1 = lane, frame, a0, a1
+        self._args = None
+        self._t1 = None
+
+    def set(self, *, a0: float | None = None, a1: float | None = None,
+            args: dict | None = None) -> None:
+        """Set args known only inside the block (rich ``args``: low-rate
+        spans only)."""
+        if a0 is not None:
+            self._a0 = a0
+        if a1 is not None:
+            self._a1 = a1
+        if args is not None:
+            self._args = args
+
+    def interval(self, t0: float, t1: float) -> None:
+        """Take the block's own ``time.perf_counter`` readings as the span's
+        interval, so that the span's duration is the very wall the block
+        reports (one clock reading, not two)."""
+        self._t0, self._t1 = t0, t1
+
+    def __enter__(self) -> "_Scope":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter() if self._t1 is None else self._t1
+        self._ann.__exit__(*exc)
+        tr = self._tr
+        tr.span(self._track, self._nid, self._t0 - tr._t0, t1 - self._t0,
+                lane=self._lane, frame=self._frame, a0=self._a0,
+                a1=self._a1, args=self._args)
+        return False
+
+
+class _NullScope:
+    """The one shared no-op scope of :class:`NullTracer`."""
+
+    __slots__ = ()
+
+    def set(self, **kw) -> None:
+        pass
+
+    def interval(self, t0: float, t1: float) -> None:
+        pass
+
+    def __enter__(self) -> "_NullScope":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NULL_SCOPE = _NullScope()
+
+
 class NullTracer:
     """The default tracer: every emit is a no-op and ``enabled`` is False,
     so call sites guard argument preparation and the traced-off hot path
@@ -335,6 +426,9 @@ class NullTracer:
 
     def instant(self, *a, **kw) -> None:
         pass
+
+    def scope(self, *a, **kw) -> _NullScope:
+        return _NULL_SCOPE
 
     def span_batch(self, *a, **kw) -> None:
         pass

@@ -66,6 +66,18 @@ class Server:
 # OULD request admission/placement over a serving pool
 # ---------------------------------------------------------------------------
 
+def _stats_args(st: ResolveStats | None) -> dict:
+    """A solve's ResolveStats as rich span args.  ``cold_dispatch=True``
+    means ``solve_time_s`` paid for at least one XLA compile, so the span's
+    duration is not steady-state solve cost."""
+    if st is None:
+        return {}
+    return dict(n_kept=int(st.n_kept), n_replaced=int(st.n_replaced),
+                cold=bool(st.cold), k=int(st.k), n_batched=int(st.n_batched),
+                n_jit_compiles=int(st.n_jit_compiles),
+                cold_dispatch=bool(st.cold_dispatch))
+
+
 class AdmissionController:
     """Epoch-based admission + placement for a serving pool.
 
@@ -94,6 +106,8 @@ class AdmissionController:
         # emitted per round when a real Tracer is attached; the NullTracer
         # default keeps this path free.
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        for name in ("solve", "admit"):
+            self.tracer.intern(name, "n_admitted", "queue_gated")
         # Per-round solve stats only — a Plan pins its bound Problem (rate
         # matrices), which must not accumulate over a long-running pool.
         self.history: list[ResolveStats] = []
@@ -123,47 +137,57 @@ class AdmissionController:
         until the next round — conservative, never over-admits.
 
         ``now_s`` timestamps this round's trace events (simulated seconds in
-        the swarm runtime); ``None`` falls back to the tracer's real-time
-        clock (``tracer.now()``) — the CLI path.
+        the swarm runtime), emitted after the round.  ``None`` is the
+        real-time path: the round is one live ``admit`` span with the
+        planner call inside it as a live ``solve`` span (``Tracer.scope``).
         """
         if isinstance(view, np.ndarray):
             view = make_view(view)
-        plan = self.planner.plan(problem, view, request_ids=request_ids)
-        self.last_queue_rejected = 0
-        if (backlog_s is not None and deadline_s is not None
-                and plan.n_admitted):
-            plan = self._queue_gate(plan, np.asarray(backlog_s, float),
-                                    deadline_s)
-        self.history.append(plan.solve_stats or ResolveStats(
-            0, plan.solution.n_admitted, problem.n_nodes, True,
-            plan.solve_time_s))
-        if self.tracer.enabled:
-            self._trace_round(plan, request_ids, now_s)
+        live = self.tracer if now_s is None else NULL_TRACER
+        with live.scope(ADMISSION, "admit") as round_span:
+            with live.scope(SOLVER, "solve") as solve_span:
+                plan = self.planner.plan(problem, view,
+                                         request_ids=request_ids)
+                if live.enabled:
+                    solve_span.set(a0=float(plan.n_admitted),
+                                   args=_stats_args(plan.solve_stats))
+            self.last_queue_rejected = 0
+            if (backlog_s is not None and deadline_s is not None
+                    and plan.n_admitted):
+                plan = self._queue_gate(plan, np.asarray(backlog_s, float),
+                                        deadline_s)
+            self.history.append(plan.solve_stats or ResolveStats(
+                0, plan.solution.n_admitted, problem.n_nodes, True,
+                plan.solve_time_s))
+            if live.enabled:
+                round_span.set(
+                    a0=float(plan.n_admitted),
+                    a1=float(self.last_queue_rejected),
+                    args={"n_admitted": int(plan.n_admitted),
+                          "queue_gated": int(self.last_queue_rejected)})
+                self._trace_verdicts(plan, request_ids, live.now())
+        if self.tracer.enabled and now_s is not None:
+            self._trace_round(plan, request_ids, float(now_s))
         return plan
 
-    def _trace_round(self, plan: Plan, request_ids, now_s) -> None:
-        """One SOLVER span per admission round (dur = the solve's wall
-        seconds, rich args from ResolveStats incl. the cold-dispatch flag)
-        plus per-request admit/reject instants on the ADMISSION track."""
+    def _trace_round(self, plan: Plan, request_ids, ts: float) -> None:
+        """Simulated time: one SOLVER span per admission round at ``ts``
+        (dur = the solve's wall seconds, rich args from ResolveStats incl.
+        the cold-dispatch flag) plus the per-request verdict instants."""
         tr = self.tracer
-        ts = float(now_s) if now_s is not None else tr.now()
-        st = plan.solve_stats
         args: dict = {"n_admitted": int(plan.n_admitted),
                       "queue_gated": int(self.last_queue_rejected)}
-        if st is not None:
-            # cold_dispatch=True means solve_time_s paid for ≥1 XLA compile
-            # — do not read this span's dur as steady-state solve cost.
-            args.update(n_kept=int(st.n_kept), n_replaced=int(st.n_replaced),
-                        cold=bool(st.cold), k=int(st.k),
-                        n_batched=int(st.n_batched),
-                        n_jit_compiles=int(st.n_jit_compiles),
-                        cold_dispatch=bool(st.cold_dispatch))
-        tr.intern("solve", "n_admitted", "queue_gated")
+        args.update(_stats_args(plan.solve_stats))
         tr.span(SOLVER, "solve", ts, float(plan.solve_time_s),
                 a0=float(plan.n_admitted),
                 a1=float(self.last_queue_rejected), args=args)
+        self._trace_verdicts(plan, request_ids, ts)
+
+    def _trace_verdicts(self, plan: Plan, request_ids, ts: float) -> None:
+        """Per-request admit/reject instants on the ADMISSION track."""
         if request_ids is None:
             return
+        tr = self.tracer
         ids = np.asarray(request_ids, np.int64)
         adm = np.asarray(plan.admitted, bool)
         tss = np.full(ids.shape[0], ts)

@@ -453,7 +453,7 @@ def _parse_degradation(spec: str | None) -> tuple[str, float] | None:
 
 
 def _stage_measurer(scn: SwarmScenario, profile: ModelProfile, seed: int,
-                    transport=None, tracer=None):
+                    transport=None):
     """Measured-seconds lookup for stage ranges: one ExecutionEngine per
     simulation, one jit + one measurement per unique (start, end) range —
     hotspot plans collapse to a handful of kernel timings.
@@ -468,8 +468,7 @@ def _stage_measurer(scn: SwarmScenario, profile: ModelProfile, seed: int,
 
     if scn.compile_cache_dir is not None:
         compile_cache.enable(scn.compile_cache_dir)
-    engine = ExecutionEngine(layer_fns_for(profile), transport=transport,
-                             tracer=tracer)
+    engine = ExecutionEngine(layer_fns_for(profile), transport=transport)
     rng = np.random.default_rng(seed)
     frame = rng.standard_normal((1, *scn.frame_hw)).astype(np.float32)
     acts: dict[int, object] = {0: frame}   # boundary activations, lazily
@@ -689,9 +688,10 @@ class _Simulation:
             from ..transport import make_transport
             self.transport = make_transport(scn.transport,
                                             group_of=mob.group_of)
+        # The measurer's engine and transport run on the wall clock, so they
+        # get no tracer: this trace is in simulated seconds (DESIGN.md §9).
         measure = (_stage_measurer(scn, profile, seed,
-                                   transport=self.transport,
-                                   tracer=self.trace)
+                                   transport=self.transport)
                    if scn.execute else None)
         self.measure = measure
         self.warm_starts = 0         # churn-rejoin warm_start invocations

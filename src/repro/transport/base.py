@@ -22,7 +22,7 @@ from typing import Protocol, runtime_checkable
 
 import numpy as np
 
-from ..obs import NULL_TRACER, TRANSPORT
+from ..obs import NULL_TRACER
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,14 +86,12 @@ class TransportBase:
         self._tracer = NULL_TRACER
 
     def set_tracer(self, tracer) -> None:
-        """Attach a :class:`repro.obs.Tracer`: every recorded shipment emits
-        one TRANSPORT span (real-time domain, ``tracer.now()``) with payload
-        bytes and realized bandwidth as args.  All backends funnel through
-        :meth:`_record`, so this is the single emission point — the engine
-        and the swarm's substrate-sampling path never double-emit."""
+        """Attach a :class:`repro.obs.Tracer`: every backend's :meth:`ship`
+        is one live TRANSPORT ``ship`` span (``Tracer.scope``; lane = src,
+        a0 = payload bytes) whose duration is the hop wall it reports."""
         self._tracer = tracer if tracer is not None else NULL_TRACER
         if self._tracer.enabled:
-            self._tracer.intern("ship", "nbytes", "bytes_per_s")
+            self._tracer.intern("ship", "nbytes")
             # Worker-process backends emit these on the per-worker
             # "transport_worker" track (lane = worker index).
             self._tracer.intern("worker_recv", "recv_s")
@@ -104,11 +102,6 @@ class TransportBase:
         ls.n += 1
         ls.nbytes += nbytes
         ls.wall_s += wall_s
-        if self._tracer.enabled:
-            self._tracer.span(
-                TRANSPORT, "ship", self._tracer.now() - wall_s, wall_s,
-                lane=src, a0=float(nbytes),
-                a1=nbytes / wall_s if wall_s > 0 else float("inf"))
 
     def measured_spb(self, n_nodes: int) -> np.ndarray:
         """(N, N) realized seconds/byte; NaN where the link was never

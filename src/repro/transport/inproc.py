@@ -16,6 +16,7 @@ import time
 import jax
 import numpy as np
 
+from ..obs import TRANSPORT
 from .base import ShipResult, TransportBase
 
 
@@ -23,8 +24,11 @@ class InProcTransport(TransportBase):
     name = "inproc"
 
     def ship(self, src_node: int, dst_node: int, array) -> ShipResult:
-        t0 = time.perf_counter()
-        host = np.asarray(jax.block_until_ready(array))
-        wall = time.perf_counter() - t0
-        self._record(src_node, dst_node, host.nbytes, wall)
-        return ShipResult(array, int(host.nbytes), wall, moved=False)
+        with self._tracer.scope(TRANSPORT, "ship", lane=src_node) as span:
+            t0 = time.perf_counter()
+            host = np.asarray(jax.block_until_ready(array))
+            t1 = time.perf_counter()
+            span.interval(t0, t1)
+            span.set(a0=host.nbytes)
+        self._record(src_node, dst_node, host.nbytes, t1 - t0)
+        return ShipResult(array, int(host.nbytes), t1 - t0, moved=False)

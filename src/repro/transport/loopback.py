@@ -31,6 +31,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from ..obs import TRANSPORT
 from .base import ShipResult, TransportBase, WorkerStats
 from .worker import (OP_HELLO, OP_QUIT, OP_REPLY, OP_SHIP, REPLY_TIMES,
                      recv_frame, send_frame)
@@ -123,23 +124,27 @@ class LoopbackTransport(TransportBase):
             self.start()
         worker = self.worker_of(dst_node)
         conn = self._conns[worker]
-        t0 = time.perf_counter()
-        host = np.ascontiguousarray(np.asarray(jax.block_until_ready(array)))
-        payload = host.tobytes()
-        send_frame(conn, OP_SHIP, payload)
-        op, reply = recv_frame(conn)
-        if op != OP_REPLY or len(reply) != len(payload) + REPLY_TIMES.size:
-            raise ConnectionError(
-                f"transport worker returned {op!r}/{len(reply)}B "
-                f"for a {len(payload)}B shipment")
-        recv_s, echo_s = REPLY_TIMES.unpack_from(reply)
-        out = np.frombuffer(reply, dtype=host.dtype,
-                            offset=REPLY_TIMES.size).reshape(host.shape)
-        wall = time.perf_counter() - t0
-        self._record(src_node, dst_node, len(payload), wall)
-        self._record_worker(worker, recv_s, echo_s)
+        with self._tracer.scope(TRANSPORT, "ship", lane=src_node) as span:
+            t0 = time.perf_counter()
+            host = np.ascontiguousarray(
+                np.asarray(jax.block_until_ready(array)))
+            payload = host.tobytes()
+            send_frame(conn, OP_SHIP, payload)
+            op, reply = recv_frame(conn)
+            if op != OP_REPLY or len(reply) != len(payload) + REPLY_TIMES.size:
+                raise ConnectionError(
+                    f"transport worker returned {op!r}/{len(reply)}B "
+                    f"for a {len(payload)}B shipment")
+            recv_s, echo_s = REPLY_TIMES.unpack_from(reply)
+            out = np.frombuffer(reply, dtype=host.dtype,
+                                offset=REPLY_TIMES.size).reshape(host.shape)
+            t1 = time.perf_counter()
+            span.interval(t0, t1)
+            span.set(a0=len(payload))
+            self._record_worker(worker, recv_s, echo_s)
+        self._record(src_node, dst_node, len(payload), t1 - t0)
         self.moved_bytes += len(payload)
-        return ShipResult(out, len(payload), wall, moved=True)
+        return ShipResult(out, len(payload), t1 - t0, moved=True)
 
     def _record_worker(self, worker: int, recv_s: float,
                        echo_s: float) -> None:

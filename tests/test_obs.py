@@ -13,9 +13,9 @@ from repro.core.mobility import RPGMobility, RPGParams
 from repro.core.planner import Plan
 from repro.core.radio import RadioParams, rate_matrix
 from repro.exec import ExecutionEngine, compile_plan, layer_fns_for
-from repro.obs import (ADMISSION, FRAMES, NULL_TRACER, QUEUE, SOLVER,
-                       Counter, Gauge, Histogram, MetricsRegistry,
-                       NullTracer, Tracer)
+from repro.obs import (ADMISSION, ENGINE, FRAMES, NULL_TRACER, QUEUE,
+                       SOLVER, TRANSPORT, Counter, Gauge, Histogram,
+                       MetricsRegistry, NullTracer, Tracer)
 from repro.runtime.serve import AdmissionController
 from repro.runtime.swarm import SwarmScenario, simulate
 
@@ -311,9 +311,9 @@ def test_cold_dispatch_flag_separates_compile_from_solve():
 # engine + transport spans
 # ---------------------------------------------------------------------------
 
-def test_engine_and_transport_spans():
-    """One ``stage`` span per launched task (backdated: compile excluded),
-    one ``ship`` span per boundary transfer, bytes accounted exactly."""
+def _two_stage_run(tracer=None):
+    """LeNet over two nodes (layers cross a link), two requests sharing
+    both stages: one batched launch per stage and one transfer each."""
     profile = lenet_profile()
     prob = _pool_problem(n_nodes=6, requests=2)
     M = prob.n_layers
@@ -322,20 +322,151 @@ def test_engine_and_transport_spans():
     sol = Solution(assign, 0.0, "feasible", 0.0, np.ones(2, bool),
                    solver="manual")
     graph = compile_plan(Plan(sol, "manual", "snapshot", prob))
-    tr = Tracer(1 << 12)
     engine = ExecutionEngine(layer_fns_for(profile, key=jax.random.PRNGKey(0)),
-                             tracer=tr)
+                             tracer=tracer)
     frames = np.random.default_rng(0).standard_normal(
         (2, 326, 595, 3)).astype(np.float32)
-    engine.run(graph, frames)
-    stages = tr.select("stage")
+    return graph, engine.run(graph, frames)
+
+
+def test_engine_and_transport_spans():
+    """Live spans: one ``launch`` per task with one ``dispatch`` inside it,
+    launch walls equal to ``StageTiming.wall_s`` and ship walls equal to
+    ``serialize_s`` (one clock reading each), bytes accounted exactly, one
+    ``done`` per request, and every event inside the one ``run``."""
+    tr = Tracer(1 << 12)
+    graph, report = _two_stage_run(tr)
+    run = tr.select("run")
+    assert run["ts"].size == 1
+    lo, hi = run["ts"][0], run["ts"][0] + run["dur"][0]
+    assert (run["a0"][0], run["a1"][0]) == (len(graph.requests),
+                                            len(graph.tasks))
+
+    launch, dispatch = tr.select("launch"), tr.select("dispatch")
+    assert launch["ts"].size == dispatch["ts"].size == len(graph.tasks)
+    np.testing.assert_array_equal(
+        launch["dur"], [t.wall_s for t in report.stage_timings])
+    np.testing.assert_array_equal(launch["a0"],
+                                  [len(t.requests) for t in graph.tasks])
+    np.testing.assert_array_equal(launch["lane"],
+                                  [t.node for t in graph.tasks])
+    eps = 1e-9
+    assert (dispatch["ts"] >= launch["ts"] - eps).all()
+    assert (dispatch["ts"] + dispatch["dur"]
+            <= launch["ts"] + launch["dur"] + eps).all()
+
     ships = tr.select("ship")
-    assert stages["ts"].size == len(graph.tasks)
-    assert (stages["dur"] > 0).all() and (stages["ts"] >= 0).all()
     assert ships["ts"].size == len(graph.transfers)
+    np.testing.assert_array_equal(
+        ships["dur"], [t.serialize_s for t in report.transfers])
     # a0 = realized bytes per shipment (batched shared stages ship once for
     # all requests, so realized >= the per-request modeled boundary bytes)
     assert ships["a0"].min() > 0
     assert ships["a0"].sum() >= max(t.nbytes for t in graph.transfers)
+
+    done = tr.select("done")
+    assert sorted(done["frame"]) == sorted(graph.requests)
+    assert (done["dur"] == -1.0).all()
+
     ev = tr.events()
     assert set(ev["track"]) == {"engine", "transport"}
+    inner = ev["name"] != "run"
+    end = ev["ts"] + np.maximum(ev["dur"], 0.0)
+    assert (ev["ts"][inner] >= lo - eps).all()
+    assert (end[inner] <= hi + eps).all()
+    # the first run compiles every shape, each outside its launch
+    assert tr.select("compile")["ts"].size == len(graph.tasks)
+    fetch = tr.select("fetch")
+    assert fetch["a0"][0] == sum(o.nbytes for o in report.outputs.values())
+
+
+def test_scope_lands_in_profiler_trace(tmp_path):
+    """``Tracer.scope`` writes ``repro.<track>.<name>`` host events into a
+    ``jax.profiler`` trace, each enclosing its ring span's interval."""
+    import glob
+
+    import jax.numpy as jnp
+
+    tr = Tracer(64)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.scope(ENGINE, "run") as span:
+            with tr.scope(TRANSPORT, "ship", lane=2):
+                jnp.ones(8).block_until_ready()
+            span.set(a0=4.0)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = {e.name: e for p in pd.planes for ln in p.lines
+            for e in ln.events if e.name.startswith("repro.")}
+    assert set(host) == {"repro.engine.run", "repro.transport.ship"}
+    run, ship = host["repro.engine.run"], host["repro.transport.ship"]
+    assert run.start_ns <= ship.start_ns
+    assert ship.start_ns + ship.duration_ns <= run.start_ns + run.duration_ns
+    for name, ev in (("run", run), ("ship", ship)):
+        assert tr.select(name)["dur"][0] <= ev.duration_ns * 1e-9 + 1e-6
+    assert tr.select("run")["a0"][0] == 4.0
+    assert tr.select("ship")["lane"][0] == 2
+
+
+def test_null_scope_is_inert_and_tracing_changes_no_output():
+    """``NullTracer.scope`` is one shared no-op; an engine run with a live
+    tracer gives the very outputs of a run without one."""
+    nt = NullTracer()
+    with nt.scope(ENGINE, "run", a0=1.0) as span:
+        span.set(a0=2.0, args={"x": 1})
+        span.interval(0.0, 1.0)
+    assert nt.scope(ENGINE, "run") is nt.scope(QUEUE, "other", lane=3)
+    assert nt.n_events == 0 and nt.events()["ts"].size == 0
+
+    _, plain = _two_stage_run()
+    tr = Tracer(1 << 12)
+    _, traced = _two_stage_run(tr)
+    assert tr.n_events > 0
+    assert plain.outputs.keys() == traced.outputs.keys()
+    for r, out in plain.outputs.items():
+        assert out.dtype == traced.outputs[r].dtype
+        np.testing.assert_array_equal(out, traced.outputs[r])
+
+
+def test_live_admission_spans():
+    """Real-time admission (no ``now_s``): one live ``admit`` span holding
+    the planner call's live ``solve`` span, with the verdicts and the
+    ResolveStats args, plus one verdict instant per request."""
+    prob = _pool_problem()
+    tr = Tracer(1 << 12)
+    ctrl = AdmissionController("ould-dp-sparse", tracer=tr)
+    ids = list(range(prob.n_requests))
+    plan = ctrl.admit(prob, prob.rates, request_ids=ids)
+    ev = tr.events()
+    spans = ev["dur"] >= 0
+    admit = spans & (ev["name"] == "admit")
+    solve = spans & (ev["name"] == "solve")
+    assert admit.sum() == 1 and solve.sum() == 1
+    a, s = int(np.flatnonzero(admit)[0]), int(np.flatnonzero(solve)[0])
+    assert ev["ts"][a] <= ev["ts"][s]
+    assert ev["ts"][s] + ev["dur"][s] <= ev["ts"][a] + ev["dur"][a] + 1e-9
+    assert ev["a0"][a] == plan.n_admitted and ev["a1"][a] == 0
+    base = tr.seq - tr.n_events
+    assert tr._rich[base + a] == {"n_admitted": int(plan.n_admitted),
+                                  "queue_gated": 0}
+    assert "cold_dispatch" in tr._rich[base + s]
+    n_verdicts = ((ev["dur"] == -1.0)
+                  & np.isin(ev["name"], ["admit", "reject"])).sum()
+    assert n_verdicts == len(ids)
+
+
+def test_executed_swarm_trace_stays_in_simulated_time():
+    """An executed swarm run measures stage walls on the wall clock; none
+    of those measurements reaches its simulated-time trace."""
+    scn = dataclasses.replace(OVERLOAD, duration_ticks=20, execute=True)
+    tr = Tracer(1 << 16)
+    r = simulate(scn, "nearest", seed=1, tracer=tr)
+    assert r.served > 0 and tr.n_events > 0
+    ev = tr.events()
+    assert not {"engine", "transport"} & set(ev["track"])
+    assert not {"stage_measure", "warm_start", "ship"} & set(ev["name"])
