@@ -135,7 +135,7 @@ _SPAN_ARGS = {
     "compile": ("layer_start", "layer_end"),
     "launch": ("batch", "units"),
     "dispatch": (),
-    "split": ("rows",),
+    "split": ("rows", "copies"),
     "fetch": ("bytes",),
     "done": (),
     "stage_measure": ("layer_start", "layer_end"),
@@ -170,6 +170,7 @@ class ExecutionEngine:
             if set_tr is not None:
                 set_tr(self.tracer)
         self._closures: dict[tuple[int, int], Callable] = {}
+        self._splitters: dict[int, Callable] = {}
         self._warm: set[tuple[int, int, tuple]] = set()
 
     # -- jit cache -----------------------------------------------------------
@@ -184,6 +185,18 @@ class ExecutionEngine:
 
             self._closures[rng] = _run
         return self._closures[rng]
+
+    def _split(self, y: jax.Array, batch: int) -> Sequence[jax.Array]:
+        """A launch's output as its ``batch`` request rows, each ``(1, ...)``:
+        batch 1 passes ``y`` through (no device op), a larger batch is cut
+        by one jitted dispatch, cached per batch size."""
+        if batch == 1:
+            return (y,)
+        split = self._splitters.get(batch)
+        if split is None:
+            split = self._splitters[batch] = jax.jit(
+                lambda y: tuple(y[b:b + 1] for b in range(batch)))
+        return split(y)
 
     def _device_put(self, x: jax.Array) -> jax.Array:
         """Shard the batch dim over the mesh when it divides evenly."""
@@ -316,9 +329,10 @@ class ExecutionEngine:
                     for r in task.requests:
                         if last[r] == i:
                             tr.instant(ENGINE, "done", now, frame=r)
-                with tr.scope(ENGINE, "split", a0=len(task.requests)):
-                    for b, r in enumerate(task.requests):
-                        acts[r] = y[b][None]
+                batch = len(task.requests)
+                with tr.scope(ENGINE, "split", a0=batch, a1=int(batch > 1)):
+                    for r, row in zip(task.requests, self._split(y, batch)):
+                        acts[r] = row
                         compute_s[r] += wall
 
             comm_s = np.zeros(graph.n_requests)
@@ -328,7 +342,8 @@ class ExecutionEngine:
             for r in graph.requests:
                 executed[r] = compute_s[r] + comm_s[r]
             with tr.scope(ENGINE, "fetch") as span:
-                outputs = {r: np.asarray(acts[r][0]) for r in graph.requests}
+                rows = jax.device_get([acts[r] for r in graph.requests])
+                outputs = {r: row[0] for r, row in zip(graph.requests, rows)}
                 span.set(a0=sum(o.nbytes for o in outputs.values()))
         return ExecutionReport(outputs, tuple(timings), tuple(records),
                                executed, compute_s, comm_s, predicted_s,

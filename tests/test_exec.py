@@ -5,6 +5,7 @@ transfer pricing consistency, and measured-latency calibration."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -15,7 +16,10 @@ from repro.core.planner import Plan
 from repro.core.radio import RadioParams, rate_matrix
 from repro.exec import (ExecutionEngine, calibrated_problem, coalesce_graphs,
                         compile_plan, layer_fns_for)
+from repro.exec.engine import StageTiming, TransferRecord
 from repro.exec.stage_graph import stage_signature
+from repro.models import cnn
+from repro.transport import make_transport
 
 MB = 1e6
 TOL = 1e-5
@@ -202,6 +206,74 @@ def test_coalesce_graphs_execution_equivalent():
             assert got.comm_s[r + 2 * i] == pytest.approx(solo.comm_s[r])
     # fewer launches than the per-round executions combined
     assert len(merged.tasks) < sum(len(g.tasks) for g in rounds)
+
+
+def _row_split_run(engine, graph, frames):
+    """The engine's former hand-off, kept as a reference: every launch
+    output split into rows by eager indexing (``y[b][None]``), every answer
+    fetched by it.  Walls are left at 0."""
+    acts = {r: jnp.asarray(frames[r][None]) for r in graph.requests}
+    links = {(link.request, link.layer): link for link in graph.transfers}
+    timings, records = [], []
+    for task in graph.tasks:
+        for r in task.requests:
+            link = links.get((r, task.layer_start))
+            if link is not None:
+                acts[r] = engine.transport.ship(link.src_node, link.dst_node,
+                                                acts[r]).array
+                records.append(TransferRecord(
+                    link.request, link.src_node, link.dst_node, link.layer,
+                    link.nbytes, link.delay_s, 0.0))
+        rows = [acts[r] for r in task.requests]
+        x = rows[0] if len(rows) == 1 else jnp.concatenate(rows)
+        y = engine.closure(task.layer_start, task.layer_end)(x)
+        timings.append(StageTiming(task.node, task.layer_start,
+                                   task.layer_end, len(rows), 0.0))
+        for b, r in enumerate(task.requests):
+            acts[r] = y[b][None]
+    outputs = {r: np.asarray(acts[r][0]) for r in graph.requests}
+    return outputs, timings, records
+
+
+def _mixed_batch_graph(kind):
+    """A graph whose tasks include batch-1 and batched launches: one plan,
+    or two admission rounds coalesced."""
+    profile = lenet_profile()
+    if kind == "plan":
+        prob = _uniform_problem(profile, requests=3)
+        return compile_plan(_manual_plan(prob, [[3, 4], [3, 4], [1, 4, 2]]))
+    prob = _uniform_problem(profile, requests=2)
+    return coalesce_graphs([compile_plan(_manual_plan(prob, sizes)) for sizes
+                            in ([[3, 4], [1, 4, 2]], [[2, 2, 1, 2], [3, 4]])])
+
+
+@pytest.mark.parametrize("transport", ["inproc", "loopback"])
+@pytest.mark.parametrize("kind", ["plan", "coalesced"])
+def test_launch_handoff_matches_row_split(kind, transport):
+    """Handing batch-1 outputs on whole and cutting batched ones in one
+    dispatch gives the eager per-row split's outputs, launches and transfers
+    bit for bit (walls aside), and the loopback run the in-proc run's."""
+    graph = _mixed_batch_graph(kind)
+    batches = {len(t.requests) for t in graph.tasks}
+    assert 1 in batches and max(batches) > 1
+    fns = cnn.lenet_layers(cnn.lenet_init(jax.random.PRNGKey(4), 32, 48))
+    frames = _frames(np.random.default_rng(5), graph.n_requests, (32, 48, 3))
+    inproc = ExecutionEngine(fns).run(graph, frames)
+    with make_transport(transport) as tp:
+        engine = ExecutionEngine(fns, transport=tp)
+        report = engine.run(graph, frames)
+        outputs, timings, records = _row_split_run(engine, graph, frames)
+
+    assert report.outputs.keys() == outputs.keys() == inproc.outputs.keys()
+    for r, ref in outputs.items():
+        got = report.outputs[r]
+        assert type(got) is type(ref) and got.dtype == ref.dtype, r
+        np.testing.assert_array_equal(got, ref)
+        np.testing.assert_array_equal(got, inproc.outputs[r])
+    assert [dataclasses.replace(t, wall_s=0.0)
+            for t in report.stage_timings] == timings
+    assert [dataclasses.replace(t, serialize_s=0.0)
+            for t in report.transfers] == records
 
 
 def test_coalesce_graphs_rejects_model_mismatch():

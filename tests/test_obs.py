@@ -311,21 +311,22 @@ def test_cold_dispatch_flag_separates_compile_from_solve():
 # engine + transport spans
 # ---------------------------------------------------------------------------
 
-def _two_stage_run(tracer=None):
-    """LeNet over two nodes (layers cross a link), two requests sharing
-    both stages: one batched launch per stage and one transfer each."""
+def _two_stage_run(tracer=None, cuts=([3, 4], [3, 4])):
+    """LeNet with request r's stage s on node s, ``cuts[r]`` its stage
+    sizes.  By default two requests share both stages over two nodes
+    (layers cross a link): one batched launch per stage and one transfer
+    each."""
     profile = lenet_profile()
-    prob = _pool_problem(n_nodes=6, requests=2)
-    M = prob.n_layers
-    assign = np.zeros((2, M), np.int64)
-    assign[:, 3:] = 1                            # 2 stages: layers cross a link
-    sol = Solution(assign, 0.0, "feasible", 0.0, np.ones(2, bool),
+    R = len(cuts)
+    prob = _pool_problem(n_nodes=6, requests=R)
+    assign = np.stack([np.repeat(np.arange(len(c)), c) for c in cuts])
+    sol = Solution(assign, 0.0, "feasible", 0.0, np.ones(R, bool),
                    solver="manual")
     graph = compile_plan(Plan(sol, "manual", "snapshot", prob))
     engine = ExecutionEngine(layer_fns_for(profile, key=jax.random.PRNGKey(0)),
                              tracer=tracer)
     frames = np.random.default_rng(0).standard_normal(
-        (2, 326, 595, 3)).astype(np.float32)
+        (R, 326, 595, 3)).astype(np.float32)
     return graph, engine.run(graph, frames)
 
 
@@ -378,6 +379,28 @@ def test_engine_and_transport_spans():
     assert tr.select("compile")["ts"].size == len(graph.tasks)
     fetch = tr.select("fetch")
     assert fetch["a0"][0] == sum(o.nbytes for o in report.outputs.values())
+
+
+@pytest.mark.parametrize("cuts", [
+    ([3, 4], [3, 4]),                 # batched tasks only
+    ([3, 4], [1, 4, 2]),              # batch-1 tasks only
+    ([3, 4], [3, 4], [1, 4, 2]),      # both
+])
+def test_split_spans_count_rows_and_copies(cuts):
+    """One ``split`` per task: a0 = its batch, a1 = the device dispatches
+    the hand-off made (0 for a batch-1 output passed on whole, 1 for a
+    batched one cut into rows); the ``fetch`` carries every answer's
+    bytes."""
+    tr = Tracer(1 << 12)
+    graph, report = _two_stage_run(tr, cuts)
+
+    split = tr.select("split")
+    batches = [len(t.requests) for t in graph.tasks]
+    np.testing.assert_array_equal(split["a0"], batches)
+    np.testing.assert_array_equal(split["a1"], [int(b > 1) for b in batches])
+    (fetch_bytes,) = tr.select("fetch")["a0"]
+    assert fetch_bytes == len(graph.requests) * 10 * 4     # f32 logits
+    assert fetch_bytes == sum(o.nbytes for o in report.outputs.values())
 
 
 def test_scope_lands_in_profiler_trace(tmp_path):
