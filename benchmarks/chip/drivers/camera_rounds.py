@@ -36,6 +36,7 @@ chain of one window round drawn from the seed:
 
 from __future__ import annotations
 
+import collections
 import importlib
 import time
 
@@ -148,10 +149,13 @@ def run(ctx) -> tuple[dict, dict]:
             sig = tuple((t.layer_start, t.layer_end, len(t.requests))
                         for t in graph.tasks)
             if sig not in cost_of:
+                bound = collections.Counter()
+                for s, e, b in sig:
+                    bound.update(costs.bound_s(cfg, s, e, b,
+                                               pk["flops_per_s"],
+                                               pk["hbm_bytes_per_s"]))
                 cost_of[sig] = (
-                    sum(costs.conv_bound_s(cfg, s, e, b, pk["flops_per_s"],
-                                           pk["hbm_bytes_per_s"])
-                        for s, e, b in sig),
+                    bound,
                     sum(b * costs.range_flops(cfg, s, e) for s, e, b in sig))
             rounds.append(dict(
                 start=ts, end=te, outputs=report.outputs,
@@ -159,7 +163,7 @@ def run(ctx) -> tuple[dict, dict]:
                 transfers=len(graph.transfers),
                 stage_s=sum(t.wall_s for t in report.stage_timings),
                 transfer_s=sum(t.serialize_s for t in report.transfers),
-                conv_bound_s=cost_of[sig][0], launch_flops=cost_of[sig][1]))
+                bound_s=cost_of[sig][0], launch_flops=cost_of[sig][1]))
         t_end = time.perf_counter()
         recorder.on = False
     ctx.window_closed()
@@ -182,13 +186,16 @@ def run(ctx) -> tuple[dict, dict]:
     readings = compare(cfg, ref, params, frames, launches, answers,
                        checked, missing)
     done = sum(len(a) for a in answers)
+    bound_s = collections.Counter()
+    for r in rounds:
+        bound_s.update(r["bound_s"])
     rec = ctx.record(
         window_s=t_end - t0, frames_done=done,
         frame_latencies_s=[r["end"] - r["start"] for r in rounds
                            for _ in r["outputs"]],
         stage_s=sum(r["stage_s"] for r in rounds),
         transfer_s=sum(r["transfer_s"] for r in rounds),
-        conv_bound_s=sum(r["conv_bound_s"] for r in rounds),
+        bound_s=bound_s,
         launch_flops=sum(r["launch_flops"] for r in rounds),
         attempted=S * len(rounds), failed=S * len(rounds) - done)
     return rec, readings

@@ -78,6 +78,17 @@ def test_hand_built_trace():
     assert bd["idle_gaps"][0][0] == "run"
 
 
+def test_conv_roofline_reads_its_kernel_bound_over_conv_time():
+    from harness import runtime
+    reader = runtime.load_module(runtime.CHIP_DIR / "metrics"
+                                 / "conv_roofline.py")
+    red = trace.reduce(hand_trace())
+    assert reader.read({"trace": red, "bound_s": {"conv": 50e-9}}) == (
+        pytest.approx(25.0))
+    # A window with no convolution to bound reads nothing, not 0.
+    assert reader.read({"trace": red, "bound_s": {"other": 1e-9}}) is None
+
+
 def test_a_trace_without_a_window_is_refused():
     with pytest.raises(RuntimeError, match="bench.window"):
         trace.reduce(hand_trace(window=False))
@@ -113,3 +124,26 @@ def test_v5e_trace_agrees_with_jax_reader():
                                       "between benchmark spans"}
     assert sum(red["idle_gap_s"].values()) == pytest.approx(
         red["window_s"] - red["busy_s"], rel=1e-6)
+
+
+def test_v5e_pallas_kernel_lands_under_pallas_call():
+    """A trace recorded on one v5e chip by ``record_attention_trace.py``:
+    one launch of the program's non-causal Pallas flash attention at
+    ViT-B/16's shape (``bench.attention``), then the same attention as
+    plain XLA ops (``bench.plain``), inside ``bench.window``.  The kernel
+    is one custom-call op whose time ``reduce`` keeps whole under the
+    primitive ``pallas_call``, named in ``op_s`` after the jitted function
+    that launched it; the plain attention's matmuls land under
+    ``dot_general`` and none of its time under ``pallas_call``."""
+    red = trace.reduce(trace.load(str(DATA / "flash_attention_v5e.xplane.pb")))
+    kernel = red["primitive_s"]["pallas_call"]
+    assert red["op_s"]["jit_vit_attention/vit_attention.1"] == kernel
+    assert red["category_s"]["custom-call"] == pytest.approx(kernel,
+                                                             abs=1e-8)
+    assert red["primitive_s"]["dot_general"] > 0
+    # The launch's whole program, kernel and the transposes and pads around
+    # it, inside the window: nothing clipped.
+    ours = sum(v for k, v in red["op_s"].items()
+               if k.startswith("jit_vit_attention/"))
+    assert kernel < ours <= red["module_s"]["jit_vit_attention"]
+    assert red["module_n"] == {"jit_vit_attention": 1, "jit_plain": 1}
