@@ -21,7 +21,7 @@ from .planner import (HorizonView, IncrementalPlanner, NoisyHorizonView,
                       available_planners, get_planner, make_view,
                       register_planner)
 from .profiles import (LayerProfile, ModelProfile, lenet_profile, lm_profile,
-                       vgg16_profile)
+                       vgg16_profile, vitb16_profile)
 from .radio import RadioParams, TpuLinkModel, rate_matrix, sinr_matrix
 
 __all__ = [
@@ -41,5 +41,5 @@ __all__ = [
     "rate_matrix", "register_planner", "sinr_matrix", "solve_heuristic",
     "solve_offline_fixed", "solve_ould", "solve_ould_mp",
     "solve_static_resolve", "stage_boundaries", "to_stages", "transfer_cost",
-    "vgg16_profile",
+    "vgg16_profile", "vitb16_profile",
 ]

@@ -191,6 +191,7 @@ class Solution:
     admitted: np.ndarray         # (R,) bool — False = request rejected
     solver: str = "ilp"
     dp_stats: "ResolveStats | None" = None  # sparse-DP provenance (k, ladder)
+    n_run_placed: int = 0        # requests placed by the run-aware DP step
 
     @property
     def n_admitted(self) -> int:
@@ -414,7 +415,8 @@ class _SparseCounters:
     """Mutable tally of what the sparse ladder actually did (one solve)."""
 
     __slots__ = ("n_runs", "n_scanned", "n_dense_equiv", "n_escalations",
-                 "n_dense_fallback", "n_batched", "n_jit_compiles")
+                 "n_dense_fallback", "n_batched", "n_jit_compiles",
+                 "n_run_placed")
 
     def __init__(self):
         self.n_runs = 0             # DP kernel invocations (incl. repairs)
@@ -424,6 +426,7 @@ class _SparseCounters:
         self.n_dense_fallback = 0   # requests that hit the dense last resort
         self.n_batched = 0          # requests served by the batched fast path
         self.n_jit_compiles = 0     # XLA compiles triggered inside the solve
+        self.n_run_placed = 0       # requests placed by the run-aware DP
 
     def wrap(self, kernel: Callable, per_run: int, dense_per_run: int):
         """Instrument ``kernel`` so every invocation (the repair loop re-runs
@@ -681,6 +684,86 @@ def _place_request(spb: np.ndarray, K: list[float], Ks: float, src: int,
     return path, cost
 
 
+def _dp_runs(spb: np.ndarray, K: list[float], Ks: float, src: int,
+             mem: list[float], comp: list[float],
+             mem_left: np.ndarray, comp_left: np.ndarray,
+             compute_cost: np.ndarray | None
+             ) -> tuple[np.ndarray | None, float]:
+    """Run-aware lattice DP: the last step of the placement ladder.
+
+    :func:`_dp_single_request` checks each unit against a node on its own,
+    so for a model of many equal units whose sum exceeds a node it stacks
+    every unit on the cheapest node, and the repair loop then shrinks that
+    node's advertisement below one unit, and the next node's, until no node
+    can hold any: the request is rejected although it fits in runs.  Here
+    the state of unit j is (node i, start s of the run of units on i that
+    ends at j), and a unit joins a run only while the run's summed m and c
+    fit what node i has left; a new run starts on another node.  One
+    vectorised (N, M) step per unit.  Each run is checked on its own: a
+    path that comes back to a node fails the joint check and is rejected.
+    """
+    N, M = spb.shape[0], len(K)
+    INF = float("inf")
+    run_m = np.full((M, M), INF)        # [s, j]: Σ m over units s..j
+    run_c = np.full((M, M), INF)
+    for s in range(M):                  # left-to-right, as the joint check
+        run_m[s, s:] = np.cumsum(mem[s:])
+        run_c[s, s:] = np.cumsum(comp[s:])
+    # fits[j][s, i]: units s..j fit node i's residual capacity.
+    fits = ((run_m.T[:, :, None] <= mem_left[None, None, :] + 1e-9)
+            & (run_c.T[:, :, None] <= comp_left[None, None, :] + 1e-9))
+    hop = spb.astype(float, copy=True)
+    np.fill_diagonal(hop, INF)          # staying put continues the run
+    cc = (np.zeros((M, N)) if compute_cost is None
+          else np.asarray(compute_cost, float))
+    cost = np.full((N, M), INF)         # [i, s] at the current unit
+    first = np.where(np.arange(N) == src, 0.0, Ks * spb[src])
+    cost[:, 0] = np.where(fits[0][0], first + cc[0], INF)
+    back_k = np.zeros((M, N), np.int64)  # a run started at j came from
+    back_s = np.zeros((M, N), np.int64)  # (node, run start) at j - 1
+    for j in range(1, M):
+        new = np.full((N, M), INF)
+        new[:, :j] = np.where(fits[j][:j].T, cost[:, :j] + cc[j][:, None],
+                              INF)
+        s_best = cost.argmin(axis=1)                       # (N,)
+        step = cost[np.arange(N), s_best][:, None] + K[j - 1] * hop
+        k_best = step.argmin(axis=0)                       # (N,)
+        new[:, j] = np.where(fits[j][j], step[k_best, np.arange(N)] + cc[j],
+                             INF)
+        back_k[j], back_s[j] = k_best, s_best[k_best]
+        cost = new
+    i, s = np.unravel_index(int(cost.argmin()), cost.shape)
+    total = float(cost[i, s])
+    if not np.isfinite(total):
+        return None, INF
+    path = np.zeros(M, np.int64)
+    for j in range(M - 1, -1, -1):
+        path[j] = i
+        if s == j and j > 0:
+            i, s = back_k[j, i], back_s[j, i]
+    if not _repair_capacity(path, mem, comp, mem_left, comp_left):
+        return None, INF
+    return path, total
+
+
+def _place_dense(spb: np.ndarray, K: list[float], Ks: float, src: int,
+                 mem: list[float], comp: list[float],
+                 mem_left: np.ndarray, comp_left: np.ndarray,
+                 compute_cost: np.ndarray | None, capacity_repair: str,
+                 counters: "_SparseCounters"
+                 ) -> tuple[np.ndarray | None, float]:
+    """The dense solvers' ladder: lattice DP and repair, then the run-aware
+    DP where they reject (counted in ``counters.n_run_placed``)."""
+    path, cost = _place_request(spb, K, Ks, src, mem, comp, mem_left,
+                                comp_left, compute_cost,
+                                capacity_repair=capacity_repair)
+    if path is None:
+        path, cost = _dp_runs(spb, K, Ks, src, mem, comp, mem_left,
+                              comp_left, compute_cost)
+        counters.n_run_placed += path is not None
+    return path, cost
+
+
 class _SparsePlacer:
     """Sequential sparse placement over one priced topology.
 
@@ -769,7 +852,18 @@ class _SparsePlacer:
     # -- placement ----------------------------------------------------------
 
     def place(self, src: int) -> tuple[np.ndarray | None, float]:
-        """Ladder placement for one request (cache replay when certified)."""
+        """Ladder placement for one request (cache replay when certified);
+        where the whole ladder rejects it, the run-aware DP."""
+        path, cost = self._replay_or_ladder(src)
+        if path is None:
+            path, cost = _dp_runs(self.spb, self.K, self.Ks, src, self.mem,
+                                  self.comp, self.mem_left, self.comp_left,
+                                  self.compute_cost)
+            if path is not None and self.counters is not None:
+                self.counters.n_run_placed += 1
+        return path, cost
+
+    def _replay_or_ladder(self, src: int) -> tuple[np.ndarray | None, float]:
         ent = self._cache.get(src)
         if ent is None:
             return self._ladder(src)
@@ -1168,10 +1262,12 @@ def _solve_dp(prob: Problem, *, include_compute: bool,
               max_path_cost: float | None = None,
               sparse_k: int | None = None, batch_solve: bool = False,
               capacity_repair: str = "halve"
-              ) -> tuple[np.ndarray, float, np.ndarray, "ResolveStats | None"]:
+              ) -> tuple[np.ndarray, float, np.ndarray, "ResolveStats | None",
+                         int]:
     """Sequential greedy-DP: requests placed one at a time (exact per request,
     greedy across requests).  Returns (assign, total_comm_latency, admitted,
-    stats); rejected rows carry the ``-1`` sentinel.  ``stats`` is None for
+    stats, requests the run-aware DP placed); rejected rows carry the ``-1``
+    sentinel.  ``stats`` is None for
     the dense scan and a :class:`ResolveStats` carrying the pruning telemetry
     (k, escalations, dense fallbacks, pruned fraction) when ``sparse_k`` is
     set.
@@ -1195,7 +1291,7 @@ def _solve_dp(prob: Problem, *, include_compute: bool,
     assign = np.full((R, M), -1, np.int64)
     admitted = np.zeros(R, bool)
     total = 0.0
-    counters = _SparseCounters() if sparse_k is not None else None
+    counters = _SparseCounters()
     placer = None
     if sparse_k is not None:
         placer = _SparsePlacer(spb, K, prob.profile.input_bytes, mem, comp,
@@ -1216,10 +1312,10 @@ def _solve_dp(prob: Problem, *, include_compute: bool,
             if placer is not None:
                 path, cost = placer.place(int(prob.sources[r]))
             else:
-                path, cost = _place_request(
+                path, cost = _place_dense(
                     spb, K, prob.profile.input_bytes, int(prob.sources[r]),
                     mem, comp, mem_left, comp_left, compute_cost,
-                    capacity_repair=capacity_repair)
+                    capacity_repair, counters)
             if path is None or (max_path_cost is not None
                                 and cost > max_path_cost):
                 admitted[r] = False
@@ -1234,15 +1330,16 @@ def _solve_dp(prob: Problem, *, include_compute: bool,
             admitted[r] = True
             total += cost
     stats = None
-    if counters is not None:
+    if sparse_k is not None:
         stats = ResolveStats(0, R, N, True, time.perf_counter() - t0,
                              k=int(sparse_k),
                              n_dense_fallback=counters.n_dense_fallback,
                              n_escalations=counters.n_escalations,
                              pruned_fraction=counters.pruned_fraction,
                              n_batched=counters.n_batched,
-                             n_jit_compiles=counters.n_jit_compiles)
-    return assign, total, admitted, stats
+                             n_jit_compiles=counters.n_jit_compiles,
+                             n_run_placed=counters.n_run_placed)
+    return assign, total, admitted, stats, counters.n_run_placed
 
 
 # ---------------------------------------------------------------------------
@@ -1286,14 +1383,15 @@ def solve_ould(prob: Problem, *, solver: Solver = "ilp",
         k = None
         if solver == "dp-sparse":
             k = sparse_k if sparse_k is not None else default_sparse_k(prob.n_nodes)
-        assign, obj, admitted, stats = _solve_dp(
+        assign, obj, admitted, stats, n_run = _solve_dp(
             prob, include_compute=include_compute,
             max_path_cost=max_path_cost, sparse_k=k,
             batch_solve=batch_solve, capacity_repair=capacity_repair)
         n_rej = int(prob.n_requests - admitted.sum())
         status = "feasible" if n_rej == 0 else f"rejected:{n_rej}"
         return Solution(assign, obj, status, time.perf_counter() - t0,
-                        admitted, solver=solver, dp_stats=stats)
+                        admitted, solver=solver, dp_stats=stats,
+                        n_run_placed=n_run)
 
     admitted = np.ones(R, bool)
     n_try = R
@@ -1345,6 +1443,9 @@ class ResolveStats:
     # ``solve_time_s`` includes first-dispatch compile time and must not be
     # read as steady-state solve cost (DESIGN.md §9).
     n_jit_compiles: int = 0
+    # Requests that the whole ladder rejected and the run-aware DP placed
+    # (:func:`_dp_runs`).
+    n_run_placed: int = 0
 
     @property
     def cold_dispatch(self) -> bool:
@@ -1542,7 +1643,8 @@ class IncrementalSolver:
             n_escalations=ds.n_escalations if ds else 0,
             pruned_fraction=ds.pruned_fraction if ds else 0.0,
             n_batched=ds.n_batched if ds else 0,
-            n_jit_compiles=ds.n_jit_compiles if ds else 0)
+            n_jit_compiles=ds.n_jit_compiles if ds else 0,
+            n_run_placed=sol.n_run_placed)
 
     def resolve(self, rates: np.ndarray, sources: np.ndarray,
                 request_ids=None,
@@ -1596,7 +1698,7 @@ class IncrementalSolver:
                 todo.append(r)
         n_kept = R - len(todo)
         sparse = self.solver == "dp-sparse"
-        counters = _SparseCounters() if sparse else None
+        counters = _SparseCounters()
         k = (self.sparse_k if self.sparse_k is not None
              else default_sparse_k(prob.n_nodes)) if sparse else 0
         placer = None
@@ -1619,11 +1721,10 @@ class IncrementalSolver:
                 if placer is not None:
                     path, cost = placer.place(int(prob.sources[r]))
                 else:
-                    path, cost = _place_request(spb, K, Ks,
-                                                int(prob.sources[r]),
-                                                mem, comp, mem_left,
-                                                comp_left, compute_cost,
-                                                capacity_repair=self.capacity_repair)
+                    path, cost = _place_dense(
+                        spb, K, Ks, int(prob.sources[r]), mem, comp,
+                        mem_left, comp_left, compute_cost,
+                        self.capacity_repair, counters)
                 if path is None or (self.max_path_cost is not None
                                     and cost > self.max_path_cost):
                     continue
@@ -1647,12 +1748,14 @@ class IncrementalSolver:
         n_rej = int(R - admitted.sum())
         status = "feasible" if n_rej == 0 else f"rejected:{n_rej}"
         sol = Solution(assign, float(total), status, dt, admitted,
-                       solver="dp-sparse-warm" if sparse else "dp-warm")
+                       solver="dp-sparse-warm" if sparse else "dp-warm",
+                       n_run_placed=counters.n_run_placed)
         return sol, ResolveStats(
             n_kept, len(todo), int(changed.sum()), False, dt, n_repriced,
             k=k,
-            n_dense_fallback=counters.n_dense_fallback if counters else 0,
-            n_escalations=counters.n_escalations if counters else 0,
-            pruned_fraction=counters.pruned_fraction if counters else 0.0,
-            n_batched=counters.n_batched if counters else 0,
-            n_jit_compiles=counters.n_jit_compiles if counters else 0)
+            n_dense_fallback=counters.n_dense_fallback,
+            n_escalations=counters.n_escalations,
+            pruned_fraction=counters.pruned_fraction,
+            n_batched=counters.n_batched,
+            n_jit_compiles=counters.n_jit_compiles,
+            n_run_placed=counters.n_run_placed)

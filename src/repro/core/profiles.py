@@ -171,6 +171,39 @@ def vgg16_profile(height: int = 326, width: int = 595, channels: int = 3,
     return ModelProfile("vgg16", tuple(layers), input_bytes)
 
 
+def vitb16_profile(height: int = 326, width: int = 595, channels: int = 3,
+                   *, patch: int = 16, d: int = 768, mlp: int = 3072,
+                   blocks: int = 12, num_classes: int = 1000,
+                   dtype_bytes: int = 4) -> ModelProfile:
+    """ViT-B/16 (arXiv:2010.11929) as 1 + blocks + 1 placement units, as
+    ``repro.models.vit`` builds it: the patch embedding of the frame
+    cropped to whole patches (class token and position table included),
+    one unit per pre-LN encoder block, the head.  FLOPs are 2 per MAC of
+    the matmuls and attention's QKᵀ and PV (4·T²·d a block); m is a unit's
+    parameters plus its input and output, as for the CNNs."""
+    tokens = (height // patch) * (width // patch) + 1
+    act = tokens * d * dtype_bytes
+    frame = height * width * channels * dtype_bytes
+    emb_p = (patch * patch * channels + 1) * d + d + tokens * d
+    layers = [LayerProfile(
+        "embed", emb_p * dtype_bytes + frame + act,
+        2.0 * (tokens - 1) * patch * patch * channels * d, act,
+        emb_p * dtype_bytes)]
+    blk_p = 4 * d + 4 * (d * d + d) + (d * mlp + mlp) + (mlp * d + d)
+    blk_f = 2.0 * tokens * d * 3 * d + 4.0 * tokens * tokens * d \
+        + 2.0 * tokens * d * d + 4.0 * tokens * d * mlp
+    for j in range(blocks):
+        layers.append(LayerProfile(f"block{j}", blk_p * dtype_bytes + 2 * act,
+                                   blk_f, act, blk_p * dtype_bytes))
+    head_p = 2 * d + d * num_classes + num_classes
+    layers.append(LayerProfile(
+        "head", head_p * dtype_bytes + act + num_classes * dtype_bytes,
+        2.0 * d * num_classes, num_classes * dtype_bytes,
+        head_p * dtype_bytes))
+    return ModelProfile("vitb16", tuple(layers),
+                        height * width * channels * 1.0)
+
+
 # ---------------------------------------------------------------------------
 # Transformer profiles — placement units are decoder blocks (+ embed / head)
 # ---------------------------------------------------------------------------

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.profiles import ModelProfile
-from ..models import cnn
+from ..models import cnn, vit
 from ..obs import ENGINE, NULL_TRACER
 from ..transport import InProcTransport, Transport
 from .stage_graph import StageGraph, StageTask
@@ -108,9 +108,9 @@ def layer_fns_for(profile: ModelProfile, params=None,
                   key=None) -> list[Callable]:
     """Per-unit apply functions matching ``profile``'s placement units.
 
-    Supports the paper's CNN workloads (``lenet`` / ``vgg16``); other
-    profiles must hand the engine their own ``layer_fns``.  ``params`` wins
-    over ``key`` (fresh init).
+    Supports the paper's CNN workloads (``lenet`` / ``vgg16``) and ViT-B/16
+    (``vitb16``); other profiles must hand the engine their own
+    ``layer_fns``.  ``params`` wins over ``key`` (fresh init).
     """
     key = key if key is not None else jax.random.PRNGKey(0)
     if profile.name == "lenet":
@@ -119,6 +119,9 @@ def layer_fns_for(profile: ModelProfile, params=None,
     elif profile.name == "vgg16":
         params = params if params is not None else cnn.vgg16_init(key)
         fns = cnn.vgg16_layers(params)
+    elif profile.name == "vitb16":
+        params = params if params is not None else vit.vitb16_init(key)
+        fns = vit.vitb16_layers(params)
     else:
         raise ValueError(
             f"no builtin layer fns for profile {profile.name!r}; "

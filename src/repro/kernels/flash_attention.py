@@ -33,6 +33,10 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
     scale = scale if scale is not None else D ** -0.5
     block_q = min(block_q, max(Sq, 8))
     block_k = min(block_k, max(Skv, 8))
+    # float32 inputs contract in three bf16 passes, as XLA's Precision.HIGH
+    # does (Mosaic's default is one; its own HIGHEST takes six).  Narrower
+    # inputs keep one.
+    dot = _dot_bf16x3 if q.dtype == jnp.float32 else jnp.dot
 
     # head-major fold: (B*Hq, S, D); KV repeated per group
     qf = q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D)
@@ -61,7 +65,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
         qb = q_ref[0].astype(jnp.float32) * scale          # (bq, d)
         kb = k_ref[0].astype(jnp.float32)                  # (bk, d)
         vb = v_ref[0].astype(jnp.float32)
-        s = qb @ kb.T                                      # (bq, bk)
+        s = dot(qb, kb.T)                                  # (bq, bk)
 
         qi = pl.program_id(1)
         q_pos = (qi * block_q + kv_offset
@@ -80,7 +84,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        acc_ref[...] = acc_ref[...] * alpha + p @ vb
+        acc_ref[...] = acc_ref[...] * alpha + dot(p, vb)
         m_ref[...] = m_new
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
 
@@ -107,3 +111,19 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
         interpret=interpret,
     )(qf, kf, vf)
     return out[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
+
+
+def _dot_bf16x3(a, b):
+    """``a @ b`` of float32 operands to ~1e-5: each split into a bf16 high
+    part and a bf16 rest, three one-pass products (hi·hi + hi·lo + lo·hi)
+    accumulated in float32."""
+    def split(x):
+        hi = x.astype(jnp.bfloat16)
+        return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    (a_hi, a_lo), (b_hi, b_lo) = split(a), split(b)
+
+    def mm(x, y):
+        return jnp.dot(x, y, preferred_element_type=jnp.float32)
+
+    return mm(a_hi, b_hi) + mm(a_hi, b_lo) + mm(a_lo, b_hi)

@@ -22,8 +22,10 @@ import numpy as np
 # Per-node memory of the executed CNN pool, sized from the profile.  LeNet
 # (~108 MB) at 128 MB: co-sourced requests must offload part of their path.
 # VGG16 (~1.0 GB, its head unit alone ~480 MB) at the paper's high memory
-# level, 512 MB: every request splits over several nodes.
-NODE_MEM_BYTES = {"lenet": 128e6, "vgg16": 512e6}
+# level, 512 MB: every request splits over several nodes.  ViT-B/16 (~410
+# MB, 12.2 GFLOP a block at 741 tokens) at the same level splits by
+# compute: its 147 GFLOP exceed a node's 95 GFLOP.
+NODE_MEM_BYTES = {"lenet": 128e6, "vgg16": 512e6, "vitb16": 512e6}
 
 
 @dataclasses.dataclass
@@ -63,7 +65,7 @@ def main(argv: list[str] | None = None) -> ServeRun:
                          "engine and report predicted vs measured latency "
                          "(plus a calibrated re-solve)")
     ap.add_argument("--model", default="lenet", choices=sorted(NODE_MEM_BYTES),
-                    help="CNN placed and executed under --execute")
+                    help="model placed and executed under --execute")
     ap.add_argument("--transport", default="inproc",
                     choices=("inproc", "loopback", "multiproc"),
                     help="byte-moving backend for --execute transfers: "
@@ -140,13 +142,13 @@ def main(argv: list[str] | None = None) -> ServeRun:
         # measured-calibrated profile — and, with a byte-moving transport,
         # on realized link bandwidth too (DESIGN.md §5/§7).
         from repro.core import (Problem, SnapshotView, get_planner,
-                                lenet_profile, vgg16_profile)
+                                lenet_profile, vgg16_profile, vitb16_profile)
         from repro.exec import (ExecutionEngine, calibrated_problem,
                                 compile_plan, layer_fns_for)
         from repro.transport import make_transport
 
-        profile = {"lenet": lenet_profile, "vgg16": vgg16_profile}[
-            args.model]()
+        profile = {"lenet": lenet_profile, "vgg16": vgg16_profile,
+                   "vitb16": vitb16_profile}[args.model]()
         rng = np.random.default_rng(0)
         # Hotspot the frames on two camera nodes; at NODE_MEM_BYTES the plan
         # has transfers for the transport to carry.

@@ -75,7 +75,8 @@ def _stats_args(st: ResolveStats | None) -> dict:
     return dict(n_kept=int(st.n_kept), n_replaced=int(st.n_replaced),
                 cold=bool(st.cold), k=int(st.k), n_batched=int(st.n_batched),
                 n_jit_compiles=int(st.n_jit_compiles),
-                cold_dispatch=bool(st.cold_dispatch))
+                cold_dispatch=bool(st.cold_dispatch),
+                n_run_placed=int(st.n_run_placed))
 
 
 class AdmissionController:

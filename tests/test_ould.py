@@ -1,12 +1,14 @@
 """OULD / OULD-MP optimization: optimality, constraints, admission."""
 
 import itertools
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
 
-from repro.core import (Problem, RPGMobility, RPGParams, evaluate,
-                        rate_matrix, solve_heuristic, solve_ould)
+from repro.core import (Problem, RPGMobility, RPGParams, SnapshotView,
+                        evaluate, rate_matrix, solve_heuristic, solve_ould)
 from repro.core.profiles import LayerProfile, ModelProfile
 
 
@@ -155,25 +157,56 @@ def _contended_problem():
                    rate_matrix(pos), np.array([1, 2, 0], np.int64))
 
 
+def _repair_stage_admitted(prob, rule):
+    """Requests the lattice DP and its repair loop admit under ``rule``,
+    placed in order against residual capacity: the ladder without its
+    run-aware last step."""
+    from repro.core.ould import _place_request
+    mem = prob.profile.memory_vector()
+    comp = prob.profile.compute_vector()
+    mem_left = prob.mem_cap.astype(float).copy()
+    comp_left = prob.comp_cap.astype(float).copy()
+    n = 0
+    for src in prob.sources:
+        path, _ = _place_request(prob.transfer_cost(),
+                                 prob.profile.output_vector(),
+                                 prob.profile.input_bytes, int(src), mem,
+                                 comp, mem_left, comp_left, None,
+                                 capacity_repair=rule)
+        if path is not None:
+            n += 1
+            for j, i in enumerate(path):
+                mem_left[i] -= mem[j]
+                comp_left[i] -= comp[j]
+    return n
+
+
 def test_gentle_repair_admits_strictly_more_under_contention():
     """`capacity_repair="gentle"` sheds `load − min hosted layer` (with a
     largest-layer peel when that cannot strictly shrink) instead of halving
-    — on this crafted contention scenario it admits strictly more requests,
-    while the default stays the pinned halving rule."""
+    — on this crafted contention scenario its repair stage admits strictly
+    more requests, while the default stays the pinned halving rule.  The
+    run-aware last step of the ladder then admits under either rule at
+    least what the gentle repair does."""
     prob = _contended_problem()
+    assert (_repair_stage_admitted(prob, "gentle")
+            > _repair_stage_admitted(prob, "halve"))
     halve = solve_ould(prob, solver="dp")
     default = solve_ould(prob, solver="dp", capacity_repair="halve")
     gentle = solve_ould(prob, solver="dp", capacity_repair="gentle")
     np.testing.assert_array_equal(halve.assign, default.assign)
-    assert int(gentle.admitted.sum()) > int(halve.admitted.sum())
-    # gentle's extra admissions still respect the joint per-node load
+    assert int(halve.admitted.sum()) >= _repair_stage_admitted(prob, "gentle")
+    assert int(gentle.admitted.sum()) >= _repair_stage_admitted(prob,
+                                                                "gentle")
+    # every admission still respects the joint per-node load
     mem = np.asarray(prob.profile.memory_vector())
-    load = np.zeros(prob.n_nodes)
-    for r in range(prob.n_requests):
-        if gentle.admitted[r]:
-            for j, i in enumerate(gentle.assign[r]):
-                load[i] += mem[j]
-    assert (load <= prob.mem_cap + 1e-9).all()
+    for sol in (halve, gentle):
+        load = np.zeros(prob.n_nodes)
+        for r in range(prob.n_requests):
+            if sol.admitted[r]:
+                for j, i in enumerate(sol.assign[r]):
+                    load[i] += mem[j]
+        assert (load <= prob.mem_cap + 1e-9).all()
 
 
 def test_capacity_repair_validated_and_threads_through_solvers():
@@ -186,3 +219,99 @@ def test_capacity_repair_validated_and_threads_through_solvers():
     sol, _ = inc.solve(prob.rates, prob.sources)
     gentle = solve_ould(prob, solver="dp", capacity_repair="gentle")
     assert int(sol.admitted.sum()) == int(gentle.admitted.sum())
+
+
+# ---------------------------------------------------------------------------
+# the run-aware last step of the placement ladder
+# ---------------------------------------------------------------------------
+
+CHIP = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+
+
+def _split_cams4(profile, sources=(0, 1, 1, 0)):
+    """The paper's deployment at its high memory level: 16 UAVs of 512 MB
+    and 95 GFLOP a decision period in a 100 m area, camera streams sourced
+    at two hotspot UAVs."""
+    n = 16
+    pos = RPGMobility(RPGParams(n_uavs=n, area_m=100.0, homogeneous=True),
+                      seed=0).positions(1, seed=0)[0]
+    return Problem(profile, np.full(n, 512e6), np.full(n, 95e9),
+                   rate_matrix(pos), np.asarray(sources, np.int64),
+                   compute_speed=np.full(n, 9.5e9))
+
+
+def _within_capacity(prob, sol):
+    mem = np.asarray(prob.profile.memory_vector())
+    comp = np.asarray(prob.profile.compute_vector())
+    m, c = np.zeros(prob.n_nodes), np.zeros(prob.n_nodes)
+    for r in np.flatnonzero(sol.admitted):
+        np.add.at(m, sol.assign[r], mem)
+        np.add.at(c, sol.assign[r], comp)
+    return bool((m <= prob.mem_cap + 1e-9).all()
+                and (c <= prob.comp_cap + 1e-9).all())
+
+
+@pytest.mark.parametrize("planner", ["ould-dp", "ould-dp-sparse",
+                                     "incremental", "incremental-sparse"])
+def test_uniform_blocks_are_placed_in_runs(planner):
+    """12 equal blocks of 12.2 GFLOP (ViT-B/16 at 741 tokens) sum past a
+    node's 95 GFLOP although each fits: the lattice DP stacks them on one
+    node and its repair, under either rule, shrinks every node below one
+    block.  The run-aware step places every stream in runs that fit."""
+    from repro.core import get_planner, vitb16_profile
+    prob = _split_cams4(vitb16_profile())
+    for rule in ("halve", "gentle"):
+        assert _repair_stage_admitted(prob, rule) == 0
+    plan = get_planner(planner).plan(prob, SnapshotView(prob.rates),
+                                     request_ids=list(range(4)))
+    assert plan.n_admitted == 4
+    assert _within_capacity(prob, plan.solution)
+    for r in range(4):
+        path = plan.solution.assign[r]
+        assert len(set(path.tolist())) >= 2
+        # runs: a node hosts one contiguous range of a frame's units
+        starts = np.flatnonzero(np.diff(path)) + 1
+        assert len(set(path.tolist())) == len(starts) + 1
+    assert plan.solution.n_run_placed == 4
+    if planner != "ould-dp":                # the dense cold solve has no stats
+        assert plan.solve_stats.n_run_placed == 4
+
+
+def test_run_placed_count_rides_the_solve_span():
+    from repro.core import vitb16_profile
+    from repro.obs import Tracer
+    from repro.runtime.serve import AdmissionController
+    prob = _split_cams4(vitb16_profile())
+    tr = Tracer(1 << 12)
+    ctrl = AdmissionController("incremental-sparse", tracer=tr)
+    ids = list(range(4))
+    first = ctrl.admit(prob, prob.rates, request_ids=ids)
+    again = ctrl.admit(prob, prob.rates, request_ids=ids)
+    assert first.solve_stats.n_run_placed == 4
+    assert again.solve_stats.n_run_placed == 0      # kept, not re-placed
+    rich = [tr._rich[k] for k in sorted(tr._rich)
+            if "n_run_placed" in tr._rich[k]]
+    assert [r["n_run_placed"] for r in rich] == [4, 0]
+
+
+def test_vgg16_split_cams4_plan_is_pinned():
+    """The VGG16 cell's plan as it stood before the run-aware step: the
+    ladder admits all four streams without it, so it never runs."""
+    sys.path.insert(0, str(CHIP))
+    from harness import runtime
+    from repro.exec import compile_plan
+    from repro.runtime.serve import AdmissionController
+    driver = runtime.load_module(CHIP / "drivers" / "camera_rounds.py")
+    cfg = runtime.load_json(CHIP / "configs" / "vgg16.json")
+    tr = runtime.load_json(CHIP / "traffic" / "split_cams4.json")
+    prob = driver.deployment(cfg, tr)
+    plan = AdmissionController(tr["planner"]).admit(
+        prob, SnapshotView(prob.rates), request_ids=list(range(4)))
+    graph = compile_plan(plan)
+    assert (len(graph.tasks), len(graph.transfers)) == (34, 36)
+    assert plan.solve_stats.n_run_placed == 0
+    assert plan.solution.assign.tolist() == [
+        [8, 6, 8, 8, 6, 8, 8, 6, 6, 8, 8, 6, 6, 8, 8, 8, 8, 7],
+        [0, 10, 0, 0, 10, 0, 0, 10, 10, 0, 0, 10, 10, 0, 0, 0, 0, 5],
+        [1, 0, 0, 8, 14, 8, 8, 14, 14, 8, 8, 14, 14, 8, 8, 8, 8, 12],
+        [1, 1, 1, 1, 1, 9, 9, 1, 1, 9, 9, 1, 1, 9, 9, 9, 9, 15]]

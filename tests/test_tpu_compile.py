@@ -124,3 +124,28 @@ def test_vgg16_stage_fits_one_chip(one_chip):
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, used
     assert c.out_info.shape == (4, 163, 297, 128)
+
+
+def test_vit_block_holds_one_flash_kernel(one_chip, monkeypatch):
+    """One encoder block of the executed ViT-B/16 (the engine closure's
+    body, unit [1, 2)) at 741 tokens, batch 2: its attention is the Pallas
+    flash kernel, the block's only custom call."""
+    from repro.kernels import ops
+    from repro.models import vit
+    monkeypatch.setenv("REPRO_KERNELS", "pallas")
+    monkeypatch.setattr(ops, "_interpret", lambda: False)   # the chip's path
+    ops._mode.cache_clear()
+    params = jax.eval_shape(lambda k: vit.vitb16_init(k, blocks=1),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), params)
+
+    def stage(p, x):
+        return cnn.apply_layers(vit.vitb16_layers(p), x, 1, 2)
+
+    try:
+        c = _compile(stage, params, _spec(one_chip, (2, 741, 768),
+                                          jnp.float32))
+    finally:
+        ops._mode.cache_clear()
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert c.out_info.shape == (2, 741, 768)
